@@ -65,7 +65,7 @@ def _iter_eqns(jaxpr):
 
 
 def _sub_jaxprs(v):
-    import jax.core as jcore
+    import jax.extend.core as jcore
 
     if isinstance(v, jcore.ClosedJaxpr):
         yield v.jaxpr

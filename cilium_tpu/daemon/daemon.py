@@ -55,6 +55,7 @@ from ..proxy import ProxyManager
 from ..sidecar import blackbox
 from ..utils import defaults
 from ..utils.controller import ControllerManager, ControllerParams
+from ..utils.jaxcache import configure_compile_cache
 from ..utils.logging import get_logger
 from ..utils.metrics import (
     EndpointCount,
@@ -289,17 +290,14 @@ class Daemon:
 
         # Initialize the accelerator backend once, on this thread, before
         # builder threads race to first-touch it (concurrent first jax use
-        # from several threads is slow and can wedge plugin backends).
+        # from several threads is slow).  A backend that fails to start
+        # fails the daemon: serving host-side only would hide the device.
         if not self.config.dry_mode:
-            try:
-                import jax
+            import jax
 
-                dev = jax.devices()[0]
-                log.with_field("device", str(dev)).info("device backend ready")
-            except Exception as e:  # noqa: BLE001 — degraded host-only mode
-                log.with_field("error", str(e)).warning(
-                    "no accelerator available; host-side verdicts only"
-                )
+            configure_compile_cache()
+            dev = jax.devices()[0]
+            log.with_field("device", str(dev)).info("device backend ready")
 
         self._started = time.time()
         self.monitor.send_agent_notification(

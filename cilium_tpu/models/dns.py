@@ -269,10 +269,13 @@ def _dns_name_span(data: jax.Array, lengths: jax.Array):
         nlab = nlab + step.astype(jnp.int32)
         return (pos, done, invalid, nlab), step
 
+    # The initial carry is derived from ``data`` (the *_like forms keep
+    # its varying axes) so that under shard_map it varies over the flow
+    # axis exactly as the body's output does.
     (pos, done, invalid, _), sep_cols = jax.lax.scan(
         body,
-        (jnp.full((f,), _QNAME_OFF, jnp.int32),
-         jnp.zeros((f,), bool), invalid0, jnp.zeros((f,), jnp.int32)),
+        (jnp.full_like(msg_len, _QNAME_OFF), jnp.zeros_like(complete),
+         invalid0, jnp.zeros_like(msg_len)),
         (jnp.arange(l, dtype=jnp.int32), data.T),
     )
     is_sep = sep_cols.T  # [F, L]: True at label-length byte positions

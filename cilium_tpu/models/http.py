@@ -32,7 +32,7 @@ Deny maps to a 403 response injected by the runtime engine
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 
 import jax
 import jax.numpy as jnp
@@ -286,6 +286,13 @@ class HttpBatchModel:
 
     def verdicts_attr(self, data, lengths, remotes):
         return http_verdicts_attr(self, data, lengths, remotes)
+
+    def dispatch_bare(self) -> "HttpBatchModel":
+        """Shape-keyed dispatch marker (see R2d2BatchModel): the
+        executable takes the tables as arguments, so every policy whose
+        tables share shapes shares one compiled judge.  The host-only
+        labels leave the pytree aux, so they do not key it."""
+        return replace(self, match_kinds=(), invariant_rows=())
 
 
 def _reduce_http_rows(rules_with_remotes) -> tuple:

@@ -77,10 +77,7 @@ def _fold_chunk(dfa: DeviceDfa, data, t0, span_start, span_end,
         # Inside shard_map the scan carry becomes device-varying (each
         # device folds its own byte slice); the initial carry must be
         # marked varying too or jax's manual-axes check rejects the scan.
-        if hasattr(jax.lax, "pcast"):
-            p0 = jax.lax.pcast(p0, (vary_axis,), to="varying")
-        elif hasattr(jax.lax, "pvary"):  # older jax
-            p0 = jax.lax.pvary(p0, (vary_axis,))
+        p0 = jax.lax.pcast(p0, (vary_axis,), to="varying")
     # delta as [R, C, S, S]: for class c, D[r, c, s, t] = 1 iff δ(s,c)=t,
     # derived from the integer-id table (padded states map to 0 but are
     # never selected: composition starts from the identity and final
@@ -161,10 +158,7 @@ def seqdfa_search_sharded(dfa_abs: DeviceDfa, data, lengths, mesh: Mesh):
 
     ``data`` is [F, W] with W divisible by the seq axis size; flows may
     simultaneously shard on a flow axis if the mesh has one."""
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     n_seq = mesh.shape[SEQ_AXIS]
     f, width = data.shape

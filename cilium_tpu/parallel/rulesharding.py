@@ -34,13 +34,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# jax.shard_map graduated from jax.experimental in 0.5; accept both so
-# the mesh code runs on the container's pinned jax too.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # pragma: no cover — depends on the installed jax
-    from jax.experimental.shard_map import shard_map
-
 from ..models.base import MAX_REMOTES, ConstVerdict, pack_remote_sets
 from ..models.http import (
     HttpBatchModel,
@@ -296,7 +289,7 @@ def mesh_dns_model(policy, ingress: bool, port: int, mesh):
     n_shards = mesh.shape[RULE_AXIS]
     fallback = build_dns_model_from_rows(rows, bucket=True)
     stacked = build_sharded_dns_from_rows(rows, n_shards, bucket=True)
-    return ShardedVerdictModel(
+    return ShardedVerdictModel.resident(
         stacked, shard_offsets(len(rows), n_shards), mesh, "dns",
         fallback=fallback, match_kinds=fallback.match_kinds,
     )
@@ -462,17 +455,22 @@ def build_sharded_kafka_model(
 
 # --- sharded evaluation ---------------------------------------------------
 
+def _on_mesh(tree, mesh):
+    """Place stacked per-shard leaves with their leading dim over
+    RULE_AXIS (the layout every sharded step's in_specs expect)."""
+    return jax.device_put(
+        tree, jax.sharding.NamedSharding(mesh, P(RULE_AXIS))
+    )
+
+
 def _local(model):
     """Drop the singleton shard dim a device sees under shard_map, and
     mark every leaf varying over FLOW_AXIS for the vma checker: model
     state mixes with flow-varying data inside lax.scan carries, whose
-    input/output varying-axis sets must agree.  (On jax < 0.6 there is
-    no vma checker and no lax.pcast — dropping the dim suffices.)"""
-    if hasattr(jax.lax, "pcast"):
-        mark = lambda x: jax.lax.pcast(x, FLOW_AXIS, to="varying")  # noqa: E731
-    else:
-        mark = lambda x: x  # noqa: E731
-    return jax.tree_util.tree_map(lambda x: mark(x[0]), model)
+    input/output varying-axis sets must agree."""
+    return jax.tree_util.tree_map(
+        lambda x: jax.lax.pcast(x[0], FLOW_AXIS, to="varying"), model
+    )
 
 
 def sharded_verdict_step(mesh, verdict_fn):
@@ -483,7 +481,7 @@ def sharded_verdict_step(mesh, verdict_fn):
 
     @jax.jit
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(RULE_AXIS), P(FLOW_AXIS), P(FLOW_AXIS), P(FLOW_AXIS)),
         out_specs=(P(FLOW_AXIS), P(FLOW_AXIS), P(FLOW_AXIS)),
@@ -508,7 +506,7 @@ def sharded_kafka_step(mesh):
 
     @jax.jit
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(RULE_AXIS), P(FLOW_AXIS), P(FLOW_AXIS)),
         out_specs=P(FLOW_AXIS),
@@ -536,7 +534,7 @@ def sharded_verdict_step_attr(mesh, attr_fn):
 
     @jax.jit
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(
             P(RULE_AXIS), P(RULE_AXIS),
@@ -640,6 +638,15 @@ class ShardedVerdictModel:
         self.fallback = fallback
         self.match_kinds = match_kinds
 
+    @classmethod
+    def resident(cls, stacked, offsets, mesh, family: str, **kw):
+        """The wrapper with its tables laid out on ``mesh`` once, at
+        build: rule shards over RULE_AXIS, replicated over FLOW_AXIS.
+        Left on the default device, every sharded call would move them
+        from there again."""
+        stacked, offsets = _on_mesh((stacked, offsets), mesh)
+        return cls(stacked, offsets, mesh, family, **kw)
+
     @property
     def n_shards(self) -> int:
         return int(self.offsets.shape[0])
@@ -735,7 +742,7 @@ def mesh_r2d2_model(policy, ingress: bool, port: int, mesh):
     stacked = build_sharded_r2d2_model(
         policy, ingress, port, n_shards, bucket=True
     )
-    return ShardedVerdictModel(
+    return ShardedVerdictModel.resident(
         stacked, shard_offsets(len(rows), n_shards), mesh, "r2d2",
         fallback=fallback, match_kinds=fallback.match_kinds,
     )
@@ -751,7 +758,7 @@ def mesh_http_model_from_rows(rows: list, mesh):
         return fallback
     n_shards = mesh.shape[RULE_AXIS]
     stacked = build_sharded_http_model(rows, n_shards)
-    return ShardedVerdictModel(
+    return ShardedVerdictModel.resident(
         stacked, shard_offsets(len(rows), n_shards), mesh, "http",
         fallback=fallback,
         match_kinds=getattr(fallback, "match_kinds", ()),
@@ -797,7 +804,7 @@ def mesh_model_from_family_rows(family: str, rows: list, mesh):
         raise ValueError(f"unknown sharded family {family!r}")
     if isinstance(fallback, ConstVerdict):
         return fallback
-    return ShardedVerdictModel(
+    return ShardedVerdictModel.resident(
         stacked, shard_offsets(len(rows), n_shards), mesh, family,
         fallback=fallback,
         match_kinds=getattr(fallback, "match_kinds", ()),
@@ -813,4 +820,5 @@ def mesh_kafka_model(rules_with_remotes: list, mesh):
     stacked = build_sharded_kafka_model(
         rules_with_remotes, mesh.shape[RULE_AXIS]
     )
-    return ShardedKafkaModel(stacked, mesh, fallback=fallback)
+    return ShardedKafkaModel(_on_mesh(stacked, mesh), mesh,
+                             fallback=fallback)

@@ -553,6 +553,16 @@ class HttpSidecarEngine(DeviceAssistedEngine):
     MAX_WIDTH = 1 << 15  # beyond this: host fallback (parser denies)
     MIN_ROWS = 64
 
+    def judge_shapes(self) -> list[tuple[int, int]]:
+        """The (rows, width) judge buckets the service compiles at
+        engine build, before traffic: every row bucket up to the engine
+        capacity at the base width.  Heads wider than MIN_WIDTH still
+        compile their bucket at first use."""
+        rows = [self.MIN_ROWS]
+        while rows[-1] < self.capacity:
+            rows.append(rows[-1] * 2)
+        return [(r, self.MIN_WIDTH) for r in rows]
+
     def _make_parser(self, conn):
         from ..proxylib.parsers.http import HttpParser
 

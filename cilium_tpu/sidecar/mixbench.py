@@ -38,6 +38,36 @@ from .client import SidecarClient
 from .service import VerdictService
 
 
+def mix_policy():
+    """The r2d2 policy every MixBench conn is bound to (port 80 ingress:
+    READ of /public/ files and HALT are allowed, everything else is
+    denied)."""
+    from cilium_tpu.proxylib import (
+        NetworkPolicy,
+        PortNetworkPolicy,
+        PortNetworkPolicyRule,
+    )
+
+    return NetworkPolicy(
+        name="mixbench",
+        policy=2,
+        ingress_per_port_policies=[
+            PortNetworkPolicy(
+                port=80,
+                rules=[
+                    PortNetworkPolicyRule(
+                        l7_proto="r2d2",
+                        l7_rules=[
+                            {"cmd": "READ", "file": "/public/.*"},
+                            {"cmd": "HALT"},
+                        ],
+                    )
+                ],
+            )
+        ],
+    )
+
+
 class MixBench:
     def __init__(
         self,
@@ -49,12 +79,6 @@ class MixBench:
         batch_flows: int = 8192,
         verdict_device: str = "default",
     ) -> None:
-        from cilium_tpu.proxylib import (
-            NetworkPolicy,
-            PortNetworkPolicy,
-            PortNetworkPolicyRule,
-        )
-
         self.pool = pool
         n_partial = int(pool * frac_partial)
         n_pipe = int(pool * frac_pipelined)
@@ -65,24 +89,7 @@ class MixBench:
             n_fast, n_partial, n_pipe, n_reply,
         )
 
-        policy = NetworkPolicy(
-            name="mixbench",
-            policy=2,
-            ingress_per_port_policies=[
-                PortNetworkPolicy(
-                    port=80,
-                    rules=[
-                        PortNetworkPolicyRule(
-                            l7_proto="r2d2",
-                            l7_rules=[
-                                {"cmd": "READ", "file": "/public/.*"},
-                                {"cmd": "HALT"},
-                            ],
-                        )
-                    ],
-                )
-            ],
-        )
+        policy = mix_policy()
         # batch_timeout_ms > 0 selects the completion-pipeline mode
         # (overlapped readbacks) — the right mode for a high-RTT device
         # link; greedy/inline mode would serialize one readback per

@@ -142,10 +142,9 @@ class DaemonConfig:
     batch_flows: int = defaults.BATCH_FLOWS
     batch_width: int = defaults.BATCH_WIDTH
     batch_timeout_ms: float = defaults.BATCH_TIMEOUT_MS
-    # Device dispatch: 'eager' pipelines per-op async dispatch (wins on
-    # high-latency device links), 'jit' compiles one executable launch
-    # per batch (wins co-located), 'auto' measures both at prewarm and
-    # keeps the faster.
+    # Device dispatch: 'eager' pipelines per-op async dispatch, 'jit'
+    # compiles one executable launch per batch, 'auto' measures both at
+    # prewarm and keeps the faster.
     dispatch_mode: str = "auto"  # auto | eager | jit
     # 'cpu' routes verdict models to the host CPU backend (removes the
     # device-link term; used by the co-located latency proof).

@@ -85,7 +85,7 @@ def test_k8s_cnp_to_sidecar_verdicts(tmp_path):
         assert pusher.nacks == 0
 
         # Datapath: a shim registers the frontend->api connection.
-        sc = SidecarClient(svc.socket_path)
+        sc = SidecarClient(svc.socket_path, timeout=120.0)
         try:
             mod = sc.open_module([])
             res, shim = sc.new_connection(
@@ -192,7 +192,7 @@ def test_daemon_restart_restores_enforcement(tmp_path):
         pusher = d2.attach_verdict_service(svc.socket_path)
         assert pusher.nacks == 0
 
-        sc = SidecarClient(svc.socket_path)
+        sc = SidecarClient(svc.socket_path, timeout=120.0)
         try:
             mod = sc.open_module([])
             res, shim = sc.new_connection(

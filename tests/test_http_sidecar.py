@@ -129,7 +129,7 @@ def service(tmp_path):
 
 
 def test_http_through_sidecar(service):
-    client = SidecarClient(service.socket_path)
+    client = SidecarClient(service.socket_path, timeout=120.0)
     try:
         mod = client.open_module([])
         assert mod != 0
@@ -191,7 +191,7 @@ def test_malformed_request_line_keeps_verdict_queue_aligned(service):
     """A frame whose request line cannot parse is denied WITHOUT a
     device verdict; a pipelined valid frame after it must still get ITS
     verdict, not the malformed frame's (policy-bypass regression)."""
-    client = SidecarClient(service.socket_path)
+    client = SidecarClient(service.socket_path, timeout=120.0)
     try:
         mod = client.open_module([])
         assert client.policy_update(mod, [http_policy()]) == int(

@@ -224,7 +224,8 @@ def test_ledger_wire_cli_status_roundtrip(tmp_path, capsys):
         ]
         assert out["formation"].keys() == truth["formation"].keys()
         events = out["compiles"]
-        assert events and events[0]["cause"] == "cold"
+        builds = [e for e in events if e["kind"] == "engine-build"]
+        assert builds and builds[0]["cause"] == "cold"
         assert any(e["cause"] == "churn-vocab" for e in events)
         # since: strictly-after filter; cause: exact-match filter.
         seq0 = events[0]["seq"]
@@ -289,7 +290,8 @@ def test_evict_then_reuse_records_churn_new_shape(tmp_path):
     """Re-tracing a shape the executable cache EVICTED is churn cost,
     not a cold start: with the shape cache clamped to one entry,
     alternating two table shapes forces evict-then-reuse every flip —
-    the FIRST trace of each shape records cold, every re-trace records
+    the FIRST trace of each shape is its build's prewarm, every
+    re-trace (the flip's prewarm re-warming the evicted shape) records
     churn-new-shape, and the resident gauge never exceeds the clamp."""
     svc = client = None
     try:
@@ -310,10 +312,10 @@ def test_evict_then_reuse_records_churn_new_shape(tmp_path):
         gather = [e for e in svc.ledger.events(n=100)
                   if e["kind"] == "jit" and e.get("role") == "gather"]
         assert len(gather) == 4, gather
-        # A cold, B cold (first traces), then A and B re-traces are
+        # A and B first traces, then A and B re-traces are
         # churn-new-shape: the ledger remembers the eviction.
         assert [e["cause"] for e in gather] == [
-            "cold", "cold", "churn-new-shape", "churn-new-shape",
+            "prewarm", "prewarm", "churn-new-shape", "churn-new-shape",
         ], gather
         shapes = [e["shape"] for e in gather]
         assert shapes[0] == shapes[2] and shapes[1] == shapes[3]

@@ -81,7 +81,7 @@ def test_daemon_policy_drives_verdict_service(world):
     # Data plane: a datapath shim registers a connection against the
     # endpoint's pushed policy (keyed by endpoint IP) with the CLIENT
     # endpoint's identity as the remote.
-    shim_client = SidecarClient(svc.socket_path)
+    shim_client = SidecarClient(svc.socket_path, timeout=120.0)
     try:
         mod = shim_client.open_module([])
         res, shim = shim_client.new_connection(

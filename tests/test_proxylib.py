@@ -192,13 +192,16 @@ def test_two_rules_same_port_first_no_l7():
     assert _logger(mod).counts() == (2, 2)
 
 
-def test_mismatching_l7_types_rejected():
+def test_mismatching_l7_types_rejected(monkeypatch):
     """Two L7 types on one port => policy update fails atomically
     (reference: TestTwoRulesOnSamePortMismatchingL7, which likewise
-    registers a dummy HTTP rule parser first)."""
-    from cilium_tpu.proxylib import register_l7_rule_parser
+    registers a dummy HTTP rule parser first — restored afterwards, so
+    later tests in this process parse HTTP rules for real)."""
+    from cilium_tpu.proxylib import parser
 
-    register_l7_rule_parser("http", lambda rule_config: [])
+    monkeypatch.setitem(
+        parser._l7_rule_parsers, "http", lambda rule_config: []
+    )
     mod = _mod()
     ins = find_instance(mod)
     with pytest.raises(PolicyParseError):

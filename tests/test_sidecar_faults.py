@@ -867,7 +867,8 @@ def test_send_marks_answered_under_write_lock(tmp_path):
             pass
 
 
-def test_cut_through_stall_on_idle_service_is_shed(tmp_path, fault_model):
+def test_cut_through_stall_on_idle_service_is_shed(tmp_path, fault_model,
+                                                   monkeypatch):
     """Greedy mode, idle service: the round runs inline on the shim
     reader thread (cut-through), where a hung device call used to be
     invisible to the stall watchdog (_busy never set — no deposal, no
@@ -886,6 +887,18 @@ def test_cut_through_stall_on_idle_service_is_shed(tmp_path, fault_model):
     try:
         _, shim = _open_conn(client, 7701)
         model = fault_model[-1]
+        # Prewarm compiled the gather executable, which never re-enters
+        # the Python model: hang its dispatch as well.
+        gathered = svc._gathered_call
+
+        def stalled_gather(*a, **kw):
+            waited = 0.0
+            while model.stall.is_set() and waited < model.MAX_STALL_S:
+                time.sleep(0.01)
+                waited += 0.01
+            return gathered(*a, **kw)
+
+        monkeypatch.setattr(svc, "_gathered_call", stalled_gather)
         model.stall.set()
         t0 = time.monotonic()
         result, entries = client._on_data_rpc(
