@@ -58,7 +58,7 @@ from ..utils import metrics
 # an in-place ``n`` accumulates over.
 OVERLOAD_WINDOW_S = 0.25
 
-# Occupancy bucket width (matches VerdictTracer.BUSY_WINDOW_S).
+# Occupancy bucket width, seconds.
 BUCKET_S = 1.0
 
 # Minimum spacing between postmortem bundles while the latch is down

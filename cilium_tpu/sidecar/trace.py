@@ -22,8 +22,16 @@ a verdict's millisecond goes.  This module owns that decomposition:
   reference pairs always-on counters with a proxy accesslog.  Slow
   exemplars optionally fan out to the monitor stream and to an access
   logger (``LogRecord.latency``).
-- **Device telemetry.**  Batch-occupancy and device-busy-fraction
-  gauges, fed from the same round stamps.
+- **Device telemetry.**  A batch-occupancy gauge, fed from the same
+  round stamps.
+- **Rounds on the profiler's clock.**  While a JAX profiler session
+  records, each closed round's boundary stamps are kept in a bounded
+  ring (:meth:`VerdictTracer.profiled_rounds`), and each close emits a
+  zero-work ``sidecar.clock`` annotation whose ``mono_ns`` stat is
+  ``time.monotonic_ns()`` read just before it begins.  In the trace,
+  ``start_ns - mono_ns`` of the anchors is the offset between the two
+  clocks, so the rounds can be laid over the device's timeline.  With
+  the profiler off this costs two ``is_enabled()`` checks a round.
 
 Timebase: ``time.monotonic()`` throughout, matching the wire batches'
 ``arrival``/deadline bookkeeping.
@@ -31,9 +39,12 @@ Timebase: ``time.monotonic()`` throughout, matching the wire batches'
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from collections import deque
+
+from jax.profiler import TraceAnnotation
 
 from ..utils import metrics
 
@@ -66,6 +77,14 @@ STAGE_SEND = "send"                # built -> verdict frames written
 STAGES = (STAGE_RING, STAGE_QUEUE, STAGE_SWAP, STAGE_REASM, STAGE_CACHE,
           STAGE_FORM, STAGE_SUBMIT, STAGE_DEVICE, STAGE_DRAIN, STAGE_SEND)
 
+# Name of the zero-work annotation that puts the program's clock into a
+# profiler trace (its one stat, ``mono_ns``, is time.monotonic_ns()).
+CLOCK_ANCHOR = "sidecar.clock"
+# Rounds kept for the profiler: many seconds of rounds at any rate the
+# chip serves, bounded all the same.
+PROFILED_ROUNDS = 65536
+_profiling = TraceAnnotation.is_enabled
+
 
 class RoundTrace:
     """Stamp carrier for one dispatch round (one path group).
@@ -78,7 +97,7 @@ class RoundTrace:
 
     __slots__ = ("path", "n", "t_admit", "t_pop", "t_form", "t_submit",
                  "t_complete", "t_drain", "t_send", "ring_s", "swap_s",
-                 "reasm_s", "cache_s", "formation")
+                 "reasm_s", "cache_s", "formation", "profiled")
 
     def __init__(self, path: str, n: int, t_admit: float, t_pop: float,
                  ring_s: float = 0.0, swap_s: float = 0.0):
@@ -118,6 +137,8 @@ class RoundTrace:
         # begin_round from the popping thread.  None when the round
         # was begun off the dispatch path (no stamp, no guess).
         self.formation = None
+        # Begun while a profiler session recorded (begin_round).
+        self.profiled = False
 
     def formed(self) -> None:
         if not self.t_form:
@@ -135,15 +156,21 @@ class RoundTrace:
         if not self.t_drain:
             self.t_drain = time.monotonic()
 
-    def stages(self) -> dict[str, float]:
-        """Stage durations in seconds (>= 0; skipped boundaries fall
-        back to the previous stamp, reading as a zero-length stage)."""
+    def stamps(self) -> tuple[float, ...]:
+        """(t_pop, t_form, t_submit, t_complete, t_drain, t_send), each
+        skipped boundary read as the stamp before it."""
         t_pop = self.t_pop
         t_form = self.t_form or t_pop
         t_submit = self.t_submit or t_form
         t_complete = self.t_complete or t_submit
         t_drain = self.t_drain or t_complete
-        t_send = self.t_send or t_drain
+        return (t_pop, t_form, t_submit, t_complete, t_drain,
+                self.t_send or t_drain)
+
+    def stages(self) -> dict[str, float]:
+        """Stage durations in seconds (>= 0; skipped boundaries fall
+        back to the previous stamp, reading as a zero-length stage)."""
+        t_pop, t_form, t_submit, t_complete, t_drain, t_send = self.stamps()
         wait = max(t_pop - self.t_admit, 0.0)
         ring = min(max(self.ring_s, 0.0), wait)
         form = max(t_form - t_pop, 0.0)
@@ -166,16 +193,14 @@ class RoundTrace:
 
 class VerdictTracer:
     """Per-service latency tracer: stage histograms, a bounded span
-    ring, slow exemplars, occupancy/busy gauges.
+    ring, slow exemplars, the occupancy gauge, and the rounds closed
+    while a profiler session records.
 
     Lock-light by design: the ring is a ``deque(maxlen=...)`` (GIL-
     atomic appends), the per-stage accumulators take ONE short lock per
     round, and the sampled-span decision is a counter compare.  Nothing
     here is per-entry.
     """
-
-    # Device-busy gauge window (seconds of wall clock per update).
-    BUSY_WINDOW_S = 1.0
 
     def __init__(self, *, sample_every: int = 4096, slow_ms: float = 50.0,
                  ring: int = 512, stage_metrics: bool = True,
@@ -196,15 +221,17 @@ class VerdictTracer:
         self.slow_exemplars = 0
         self.shed_spans = 0
         self._sample_credit = 0
-        # Device-busy window accounting.
-        self._win_start = time.monotonic()
-        self._win_device_s = 0.0
+        # Rounds closed while a profiler session recorded, and whether
+        # the last round checked saw one (_profiler_check).
+        self._profiled: deque = deque(maxlen=PROFILED_ROUNDS)
+        self._profiler_on = False
+        self._round_ids = itertools.count()
         # Optional fan-out for slow exemplars.
         self.monitor = None          # monitor.Monitor (notify())
         self.access_logger = None    # accesslog.logger.AccessLogger (log())
         # Optional flight recorder (blackbox.FlightRecorder): fed the
-        # same per-round numbers the busy gauge uses, so the occupancy
-        # time-series costs no extra stamps.
+        # same per-round numbers the stage histograms use, so the
+        # occupancy time-series costs no extra stamps.
         self.recorder = None
         # Optional device ledger (ledger.DeviceLedger): fed the
         # formation stamp the dispatcher left on the popping thread —
@@ -225,12 +252,24 @@ class VerdictTracer:
         rt.formation = getattr(
             threading.current_thread(), "_disp_pop", None
         )
+        rt.profiled = self._profiler_check()
         return rt
+
+    def _profiler_check(self) -> bool:
+        """Whether a profiler session records now; the first round that
+        sees a new session clears the last one's rounds."""
+        on = _profiling()
+        if on != self._profiler_on:
+            if on:
+                self._profiled.clear()
+            self._profiler_on = on
+        return on
 
     def finish_round(self, rt: RoundTrace, batches=()) -> None:
         """Close a round: observe each stage once, the e2e histogram
-        once per covered wire batch, refresh the gauges, and capture
-        sampled/slow spans.  ``batches`` is an iterable of
+        once per covered wire batch, refresh the occupancy gauge, capture
+        sampled/slow spans, and keep the round while a profiler session
+        records.  ``batches`` is an iterable of
         ``(seq, n, arrival, conn0)`` describing the wire batches the
         round answered."""
         now = time.monotonic()
@@ -270,16 +309,6 @@ class VerdictTracer:
                     rec = self._acc[(stage, path)] = [0, 0.0]
                 rec[0] += 1
                 rec[1] += stages[stage]
-            # Device-busy fraction, windowed.
-            self._win_device_s += stages[STAGE_DEVICE]
-            span = now - self._win_start
-            if span >= self.BUSY_WINDOW_S:
-                if self.stage_metrics:
-                    metrics.DeviceBusyFraction.set(
-                        min(self._win_device_s / span, 1.0)
-                    )
-                self._win_start = now
-                self._win_device_s = 0.0
             sample = False
             if self.sample_every:
                 self._sample_credit += rt.n
@@ -322,6 +351,33 @@ class VerdictTracer:
                 )
             except Exception:  # noqa: BLE001 — ledger must not cost the round
                 pass
+        if rt.profiled or self._profiler_check():
+            # Kept also when the session stopped mid-round, so a round
+            # that straddles the trace's end is not lost.
+            self._profile(rt, stages)
+
+    def _profile(self, rt: RoundTrace, stages: dict) -> None:
+        with TraceAnnotation(CLOCK_ANCHOR, mono_ns=time.monotonic_ns()):
+            pass
+        t_pop, t_form, t_submit, t_complete, t_drain, t_send = rt.stamps()
+        self._profiled.append({
+            "id": next(self._round_ids), "path": rt.path, "n": rt.n,
+            "t_admit": rt.t_admit, "t_pop": t_pop, "t_form": t_form,
+            "t_submit": t_submit, "t_complete": t_complete,
+            "t_drain": t_drain, "t_send": t_send,
+            "swap": stages[STAGE_SWAP], "reasm": stages[STAGE_REASM],
+            "cache": stages[STAGE_CACHE],
+        })
+
+    def profiled_rounds(self) -> list[dict]:
+        """The rounds closed while a profiler session recorded (the
+        latest session's, oldest first, at most ``PROFILED_ROUNDS``):
+        id, path, n, the ``t_*`` boundary stamps on ``time.monotonic()``
+        (a skipped boundary reads as the stamp before it) and the
+        ``swap``/``reasm``/``cache`` carve-outs of batch formation, in
+        seconds.  The trace's ``sidecar.clock`` anchors map the stamps
+        onto its clock."""
+        return list(self._profiled)
 
     def record_shed(self, seq: int, n: int, arrival: float, conn0: int,
                     reason: str, session: int = 0) -> None:

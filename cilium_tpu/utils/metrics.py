@@ -523,11 +523,6 @@ VerdictBatchOccupancy = registry.gauge(
     "verdict_batch_occupancy",
     "Entries in the last dispatch round / configured batch capacity",
 )
-DeviceBusyFraction = registry.gauge(
-    "verdict_device_busy_fraction",
-    "Fraction of wall-clock spent in the device stage (fenced "
-    "submit->complete), windowed over the last ~1s of rounds",
-)
 VerdictTraceSpans = registry.counter(
     "verdict_trace_spans_total",
     "Per-entry verdict spans captured by the trace ring "

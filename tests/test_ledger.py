@@ -175,7 +175,14 @@ def test_service_stamps_formation_once_per_round(tmp_path):
         # Each further dispatch adds exactly one stamped round.
         for fr in (b"READ /public/d\r\n", b"READ /public/e\r\n"):
             assert shim.on_io(False, fr)[0] == int(FilterResult.OK)
-        ct = svc.ledger.formation()["cut-through"]
+        # The stamp rides the round's close, which may still be running
+        # on the service's side when the answer has already arrived.
+        deadline = time.monotonic() + 10
+        while time.monotonic() < deadline:
+            ct = svc.ledger.formation()["cut-through"]
+            if ct["rounds"] >= rounds0 + 2:
+                break
+            time.sleep(0.01)
         assert ct["rounds"] == rounds0 + 2, ct
         # The ledger status tallies every stamped round.
         assert svc.ledger.status()["rounds"] >= rounds0 + 2
