@@ -551,6 +551,10 @@ class VerdictService:
         self.mesh_rebind_rebuilds = 0
         self.vec_batches = 0
         self.vec_entries = 0
+        # Rounds (and their entries) judged whole by _run_mat_group: one
+        # gather, one issue and one answer per client for the round.
+        self.whole_rounds = 0
+        self.whole_entries = 0
         # Completion pipeline: the dispatcher issues device calls without
         # blocking (jax arrays are futures); this FIFO queue + worker
         # materializes results and sends responses, so host batch
@@ -1187,6 +1191,13 @@ class VerdictService:
             "denied": self.fast_log.denied,
             "vec_batches": self.vec_batches,
             "vec_entries": self.vec_entries,
+            # How often the whole-round matrix path engages (of the
+            # vec_batches/vec_entries above); the rest of the matrix
+            # rounds took the per-item route.
+            "vec": {
+                "whole_rounds": self.whole_rounds,
+                "whole_entries": self.whole_entries,
+            },
             "inline_batches": self.inline_batches,
             "dispatcher": {
                 "batches": self.dispatcher.batches,
@@ -3044,13 +3055,16 @@ class VerdictService:
         return max(waits) if waits else 0.0
 
     def _run_mat_group(self, items: list, t_pop: float) -> bool:
-        """Whole-round fast path: every item is a complete-flag matrix
-        batch, judged with ONE eligibility gather, ONE (chunked) device
-        dispatch, ONE batched readback, and ONE verdict frame per
-        client.  This collapses the per-item costs that dominate
-        aggregated rounds (measured: eligibility 17µs + frame 14µs +
-        client unpack 8µs per item).  Returns False — with no side
-        effects — when the group needs the general path."""
+        """Whole-round path: every item is a complete-flag matrix batch
+        of the configured width, judged as ONE round — one concatenation,
+        one eligibility gather of engine, dirty and remotes under one
+        _lock trip, one (chunked) device issue with the remotes in hand,
+        and one verdict frame per client.  This collapses the per-item
+        costs that dominate aggregated rounds (measured: eligibility
+        17µs + frame 14µs + client unpack 8µs per item).  Both
+        completion modes run it; only the completion differs
+        (_issue_mat_round).  Returns False — with no side effects — when
+        the round needs the per-item route."""
         stages = self.seam_stages if self.config.seam_probe else None
         t0 = time.thread_time() if stages is not None else 0.0
 
@@ -3072,16 +3086,20 @@ class VerdictService:
         else:
             ids = np.concatenate([it[2].conn_ids for it in items])
             lengths = np.concatenate([it[2].lengths for it in items])
-            rows = np.vstack([it[2].rows for it in items])
+            rows = np.concatenate([it[2].rows for it in items])
         n = len(ids)
         if n == 0:
             return False
+        # Range check on the raw u64 ids: an int64 view wraps ids past
+        # 2**63 negative, and a negative fancy index reads another
+        # conn's row.
+        top = int(ids.max())
         idx = ids.astype(np.int64)
         mark("concat")
         t_before = time.monotonic()
         with self._lock:
             swap_s = self._swap_overlap(t_before)
-            if self._tab_size == 0 or int(idx.max()) >= self._tab_size:
+            if top >= self._tab_size:
                 return False
             eng_idx = self._tab_engine[idx]
             e0 = int(eng_idx[0])
@@ -3091,7 +3109,11 @@ class VerdictService:
                 return False
             remotes = self._tab_src[idx]
             engine = self._engine_objs[e0]
-        if engine is None or isinstance(engine.model, ConstVerdict):
+        if (
+            engine is None
+            or isinstance(engine.model, ConstVerdict)
+            or _engine_framing(engine) is None
+        ):
             return False
         if int(lengths.min()) < 2 or int(lengths.max()) > self.config.batch_width:
             return False
@@ -3101,57 +3123,69 @@ class VerdictService:
             ring_s=self._ring_wait(items), swap_s=swap_s,
         )
         rt.formed()
-        # Issue device chunks with the precomputed remotes, then one
-        # batched readback for the whole round.
-        lens32 = lengths.astype(np.int32)
-        issued = []
-        max_chunk = self.config.batch_flows
-        for a in range(0, n, max_chunk):
-            b = min(a + max_chunk, n)
-            cn = b - a
-            f_pad = self._min_bucket
-            while f_pad < cn:
-                f_pad *= 2
-            if cn == f_pad:
-                data, lens, rem = rows[a:b], lens32[a:b], remotes[a:b]
-            else:
-                data = np.zeros((f_pad, self.config.batch_width), np.uint8)
-                data[:cn] = rows[a:b]
-                lens = np.zeros(f_pad, np.int32)
-                lens[:cn] = lens32[a:b]
-                rem = np.zeros(f_pad, np.int32)
-                rem[:cn] = remotes[a:b]
-            _, _, chunk_allow, chunk_rule = self._model_call_attr(
-                engine.model, data, lens, rem
-            )
-            issued.append((chunk_allow, chunk_rule, a, b, cn))
+        self.whole_rounds += 1
+        self.whole_entries += n
+        metrics.VerdictWholeRounds.inc()
+        metrics.VerdictWholeEntries.inc(amount=n)
+        self._issue_mat_round(items, engine, ids, lengths, rows, remotes,
+                              rt, mark)
+        return True
+
+    def _issue_mat_round(self, items: list, engine, ids, lengths, rows,
+                         remotes, rt, mark=lambda _stage: None) -> None:
+        """Issue a formed matrix group — whole round or the per-item
+        route's engine group — with its remotes in hand, then complete
+        it as the mode says: greedy reads back inline and answers on
+        this thread; pipelined hands ONE record to the completion
+        pipeline, tagged with this round's id so a round the stall
+        watchdog shed is never also answered, and the send loop emits
+        it in FIFO order."""
+        n = len(ids)
+        issued = self._issue_chunks(
+            engine, rows, lengths.astype(np.int32), remotes
+        )
         mark("device_issue")
         rt.submitted()
+        if not self._inline_complete:
+            self._completion_put(
+                ("mat", issued, n, items, rt, engine, ids, lengths)
+            )
+            return
         allow, rules = self._readback_chunks(issued, n)
-        mark("readback")
         # Device-complete is this FENCED boundary (np.asarray readback)
         # — block_until_ready was observed returning pre-execution
         # (BENCH_NOTES r4) and would book device time into the send stage.
         rt.completed()
+        mark("readback")
+        self._answer_mat_round(items, engine, ids, lengths, allow, rules, rt)
+        mark("respond")
+
+    def _answer_mat_round(self, items: list, engine, ids, lengths, allow,
+                          rules, rt) -> None:
+        """Answer a matrix group from its verdict arrays: one frame per
+        client — a plain VERDICT_BATCH for a single seq, a VERDICT_MULTI
+        covering all its seqs otherwise — each body built by one
+        _verdict_body over the client's slice of the round."""
+        n = len(ids)
         self.fast_log.log_batch(
             getattr(engine, "proto", "r2d2"), n, int(n - allow.sum())
         )
         self.vec_batches += 1
         self.vec_entries += n
         metrics.ProxyBatches.inc()
-        # Responses: one frame per client — a plain VERDICT_BATCH for a
-        # single seq, a VERDICT_MULTI covering all its seqs otherwise.
         per_client: dict[int, list] = {}
         start = 0
         for _, client, mb in items:
-            per_client.setdefault(id(client), [client, [], [], [], []])
-            rec = per_client[id(client)]
+            rec = per_client.get(id(client))
+            if rec is None:
+                rec = per_client[id(client)] = [client, [], [], [], []]
             rec[1].append(mb.seq)
             rec[2].append(mb.count)
             rec[3].append((start, start + mb.count))
             rec[4].append(mb)
             start += mb.count
         rt.drained()
+        deny_inject = getattr(engine, "DENY_INJECT", None)
         for client, seqs, counts, spans, mbs in per_client.values():
             # ``batches=mbs``: send() marks every covered wire batch
             # answered under the write lock before writing, so a stall
@@ -3164,7 +3198,7 @@ class VerdictService:
                         wire.MSG_VERDICT_BATCH,
                         self._verdict_frame(
                             seqs[0], ids[a:b], lengths[a:b], allow[a:b],
-                            getattr(engine, "DENY_INJECT", None),
+                            deny_inject,
                         ),
                         batches=mbs,
                     )
@@ -3172,56 +3206,55 @@ class VerdictService:
                 if spans[-1][1] - spans[0][0] == sum(counts):
                     # Contiguous spans (the single-client round and any
                     # unbroken run): zero-copy views.
-                    a, b = spans[0][0], spans[-1][1]
-                    c_ids, c_lens, c_allow = ids[a:b], lengths[a:b], allow[a:b]
+                    sel = slice(spans[0][0], spans[-1][1])
                 else:
                     sel = np.concatenate(
                         [np.arange(a, b) for a, b in spans]
                     )
-                    c_ids, c_lens, c_allow = ids[sel], lengths[sel], allow[sel]
-                body = self._verdict_body(
-                    c_ids, c_lens, c_allow,
-                    getattr(engine, "DENY_INJECT", None),
-                )
                 client.send(
                     wire.MSG_VERDICT_MULTI,
-                    wire.pack_verdict_multi(seqs, counts, len(c_ids), body),
+                    wire.pack_verdict_multi(
+                        seqs, counts, sum(counts),
+                        self._verdict_body(
+                            ids[sel], lengths[sel], allow[sel], deny_inject
+                        ),
+                    ),
                     batches=mbs,
                 )
-            except Exception:  # noqa: BLE001 — client may be gone
-                log.exception("verdict send failed")
-        mark("respond")
+            except Exception:  # noqa: BLE001
+                # A frame that could not be built: fail closed for the
+                # client's batches this round has not answered.
+                log.exception("verdict answer failed")
+                for mb in mbs:
+                    if not mb.answered:
+                        self._answer_error(client, mb)
         if not self._round_thread_suppressed():
             self.tracer.finish_round(
                 rt, [self._batch_desc(it[2], it[1]) for it in items]
             )
             self._record_vec_round(engine, ids, allow, rules)
-        return True
 
     def _readback_chunks(self, issued: list, n: int):
         """Materialize a round's (allow, rule) chunk futures into host
         arrays.  np.asarray per array beats one batched device_get for
         the typical 1-2 co-located chunks (measured 3µs vs 20µs).
-        Device errors deny (and unattribute) the chunk."""
-        allow = np.empty(n, bool)
-        rules = np.full(n, -1, np.int32)
-        for fut, rfut, a, b, cn in issued:
+        Device errors deny (and unattribute) the chunk; a failed rule
+        readback only unattributes it — the rule array exists for
+        OBSERVABILITY and must never flip verdicts that materialized."""
+        vals = []
+        for fut, rfut, _, _, _ in issued:
             try:
-                allow[a:b] = np.asarray(fut)[:cn]
+                vals.append(np.asarray(fut))
             except Exception:  # noqa: BLE001 — deny on device error
                 log.exception("device readback failed")
-                allow[a:b] = False
-                continue
+                vals.append(None)
             if rfut is not None:
-                # Separate containment: the rule array exists for
-                # OBSERVABILITY only — a failed rule readback
-                # unattributes the chunk, it must never flip verdicts
-                # that already materialized successfully.
                 try:
-                    rules[a:b] = np.asarray(rfut)[:cn]
+                    vals.append(np.asarray(rfut))
                 except Exception:  # noqa: BLE001 — unattribute only
                     log.exception("rule-attribution readback failed")
-        return allow, rules
+                    vals.append(None)
+        return self._chunk_values(issued, n, vals)
 
     def _record_vec_round(self, engine, conn_ids, allow, rules) -> None:
         """One flow-record batch for a vec/matrix round: columnar
@@ -3768,12 +3801,12 @@ class VerdictService:
                         self.close_connection(*close_args)
                     self._round_record_ok()
                     return
-        # Whole-round fast path (greedy mode): every data item a
+        # Whole-round path (either completion mode): every data item a
         # complete-flag matrix batch of the configured width — one
-        # grouped eligibility/dispatch/readback/response pass.
+        # grouped eligibility/issue/response pass; a round it declines
+        # falls through, untouched, to the per-item route below.
         if (
             not quarantined
-            and self._inline_complete
             and data_items
             and all(
                 it[0] == "mat"
@@ -5470,8 +5503,11 @@ class VerdictService:
 
     def _run_vec(self, vec_items: list, snap: "_TabSnap",
                  t_pop: float) -> None:
-        """One device call per engine chunk over the concatenated
-        batches, ops emitted columnar straight from the verdict arrays."""
+        """The per-item route's vec items: per engine, one device pass
+        over the group's concatenated matrix batches (issued and
+        answered as _run_mat_group's are) and one over its DATA
+        batches, ops emitted columnar straight from the verdict
+        arrays."""
         self._count_cache_misses(
             sum(it[2].count for it, _ in vec_items)
         )
@@ -5487,8 +5523,9 @@ class VerdictService:
             mats = [it for it, _ in group if it[0] == "mat"]
             datas = [it for it, _ in group if it[0] == "data"]
             # Matrix items arrive pre-padded: device chunks are plain
-            # row-slices, no gather.  Aggregate across items so one
-            # device pass covers the whole round.
+            # row-slices, no gather.  The group is issued and answered
+            # like a whole round (one frame per client), with its
+            # remotes from the round's snapshot.
             if mats:
                 rt = self.tracer.begin_round(
                     PATH_VEC, sum(it[2].count for it in mats),
@@ -5498,30 +5535,17 @@ class VerdictService:
                 swap_s = 0.0
                 if len(mats) == 1:
                     m_rows = mats[0][2].rows
-                    m_lens = mats[0][2].lengths.astype(np.int32)
+                    m_lens = mats[0][2].lengths
                     m_ids = mats[0][2].conn_ids
                 else:
                     m_rows = np.concatenate([it[2].rows for it in mats])
-                    m_lens = np.concatenate(
-                        [it[2].lengths for it in mats]
-                    ).astype(np.int32)
+                    m_lens = np.concatenate([it[2].lengths for it in mats])
                     m_ids = np.concatenate([it[2].conn_ids for it in mats])
                 rt.formed()
-                issued = self._issue_chunks(engine, m_rows, m_lens, m_ids, snap)
-                rt.submitted()
-                sends, start = [], 0
-                for _, client, mb in mats:
-                    sends.append(
-                        (client, mb.seq, mb.conn_ids, mb.lengths,
-                         start, start + mb.count, mb)
-                    )
-                    start += mb.count
-                if self._inline_complete:
-                    self._finish_vec(issued, start, sends, rt, engine)
-                else:
-                    self._completion_put(
-                        ("vec", issued, start, sends, rt, engine)
-                    )
+                self._issue_mat_round(
+                    mats, engine, m_ids, m_lens, m_rows,
+                    snap.src[snap.lookup(m_ids)], rt,
+                )
             if not datas:
                 continue
             rt = self.tracer.begin_round(
@@ -5560,13 +5584,13 @@ class VerdictService:
             else:
                 self._completion_put(("vec", issued, n, sends, rt, engine))
 
-    def _issue_chunks(self, engine, rows, lengths, conn_ids,
-                      snap: "_TabSnap") -> list:
-        """Issue device calls over [n, width] rows in fixed bucket-shaped
-        chunks WITHOUT blocking; returns [(allow_future, rule_future,
-        a, b, cn)] (rule None without attribution) for the completion
-        worker to materialize."""
-        n = len(conn_ids)
+    def _issue_chunks(self, engine, rows, lengths, remotes) -> list:
+        """Issue device calls over [n, width] rows (int32 lengths, int32
+        remote identities) in fixed bucket-shaped chunks WITHOUT
+        blocking; returns [(allow_future, rule_future, a, b, cn)] (rule
+        None without attribution) for the completion worker to
+        materialize."""
+        n = len(lengths)
         width = rows.shape[1]
         issued = []
         max_chunk = self.config.batch_flows
@@ -5581,15 +5605,16 @@ class VerdictService:
                 # (saves a ~0.5MB memcpy per full chunk on the hot path).
                 data = rows[a:b]
                 lens = lengths[a:b]
+                rem = remotes[a:b]
             else:
                 data = np.zeros((f_pad, width), np.uint8)
                 data[:cn] = rows[a:b]
                 lens = np.zeros(f_pad, np.int32)
                 lens[:cn] = lengths[a:b]
-            remotes = np.zeros(f_pad, np.int32)
-            remotes[:cn] = snap.src[snap.lookup(conn_ids[a:b])]
+                rem = np.zeros(f_pad, np.int32)
+                rem[:cn] = remotes[a:b]
             _, _, chunk_allow, chunk_rule = self._model_call_attr(
-                engine.model, data, lens, remotes
+                engine.model, data, lens, rem
             )
             if self._inline_complete and hasattr(chunk_allow, "copy_to_host_async"):
                 # Co-located/greedy mode materializes chunks
@@ -5702,7 +5727,7 @@ class VerdictService:
         rid = getattr(threading.current_thread(), "_disp_round", None)
         self._completions.put((rid, rec))
 
-    def _finish_vec(self, issued, n, sends, rt=None, engine=None) -> None:
+    def _finish_vec(self, issued, n, sends, rt, engine) -> None:
         """Inline completion (greedy mode): materialize this round's
         futures and send — runs on the dispatcher thread, so per-conn
         FIFO order is trivially preserved.  The queue/worker variant in
@@ -5710,28 +5735,8 @@ class VerdictService:
         Failures are isolated per chunk/per client like the queue path:
         one dead client or device error must not abort the round."""
         allow, rules = self._readback_chunks(issued, n)
-        if rt is not None:
-            rt.completed()  # fenced: np.asarray above IS the readback
-        self.fast_log.log_batch(
-            getattr(engine, "proto", "r2d2"), n, int(n - allow.sum())
-        )
-        self.vec_batches += 1
-        self.vec_entries += n
-        metrics.ProxyBatches.inc()
-        self._send_vec_frames(
-            sends, allow, getattr(engine, "DENY_INJECT", None)
-        )
-        if not self._round_thread_suppressed():
-            if rt is not None:
-                self.tracer.finish_round(
-                    rt, [self._batch_desc(s[6], s[0]) for s in sends]
-                )
-            if engine is not None and sends:
-                self._record_vec_round(
-                    engine,
-                    np.concatenate([s[2] for s in sends]),
-                    allow, rules,
-                )
+        rt.completed()  # fenced: np.asarray above IS the readback
+        self._answer_vec_round(sends, n, rt, engine, allow, rules)
 
     def _send_vec_frames(self, sends, allow,
                          deny_inject: bytes | None = None) -> None:
@@ -5750,22 +5755,7 @@ class VerdictService:
                 )
             except Exception:  # noqa: BLE001
                 log.exception("verdict frame build failed")
-                # Fail closed, never silent: the shim is owed exactly
-                # one reply for this seq, and nothing downstream will
-                # answer it (the round completes normally).
-                try:
-                    sent = client.send_verdicts(
-                        seq,
-                        self._typed_entries(
-                            batch, FilterResult.UNKNOWN_ERROR
-                        ),
-                        batch=batch,
-                    )
-                except Exception:  # noqa: BLE001
-                    log.exception("error response send failed")
-                    continue
-                if sent:  # see _shed_item: no double-booking
-                    self.error_entries += batch.count
+                self._answer_error(client, batch)
                 continue
             _, frames, bs = per_client.setdefault(
                 id(client), (client, [], [])
@@ -5779,6 +5769,23 @@ class VerdictService:
                 )
             except Exception:  # noqa: BLE001 — client may be gone
                 log.exception("verdict send failed")
+
+    def _answer_error(self, client, batch) -> None:
+        """Fail closed, never silent, when a wire batch's verdict frame
+        could not be built: the shim is owed exactly one reply for the
+        seq, and nothing downstream will answer it (the round completes
+        normally) — a typed UNKNOWN_ERROR per entry."""
+        try:
+            sent = client.send_verdicts(
+                batch.seq,
+                self._typed_entries(batch, FilterResult.UNKNOWN_ERROR),
+                batch=batch,
+            )
+        except Exception:  # noqa: BLE001
+            log.exception("error response send failed")
+            return
+        if sent:  # see _shed_item: no double-booking
+            self.error_entries += batch.count
 
     # Max concurrent device->host readbacks.  Measured on the rounds 1–5
     # chip: one batched jax.device_get costs ~1 link RTT regardless of
@@ -5829,7 +5836,7 @@ class VerdictService:
             stop = any(r[0] == "stop" for _rid, r in recs)
             futs = []
             for _rid, r in recs:
-                if r[0] == "vec":
+                if r[0] in ("vec", "mat"):
                     # Per chunk: the allow future, then (attribution
                     # on) the rule future — the send loop consumes
                     # them in the same order.
@@ -5888,7 +5895,7 @@ class VerdictService:
             # earlier records' sends run, or later groups would book
             # sibling send time as device time.
             for _rid, r in recs:
-                if r[0] == "vec":
+                if r[0] in ("vec", "mat"):
                     r[4].completed()
             vi = 0
             cur = threading.current_thread()
@@ -5906,50 +5913,29 @@ class VerdictService:
                 cur._disp_round = rid
                 try:
                     deposed = self.dispatcher.thread_round_is_shed()
-                    if r[0] == "vec":
-                        _, issued, n, sends, rt, engine = r
+                    if r[0] in ("vec", "mat"):
+                        issued = r[1]
                         n_futs_round = sum(
                             2 if rfut is not None else 1
                             for _, rfut, _, _, _ in issued
                         )
+                        chunk = vals[vi : vi + n_futs_round]
+                        vi += n_futs_round  # keep later slices aligned
                         if deposed:
-                            vi += n_futs_round  # keep later slices aligned
                             continue
-                        allow = np.empty(n, bool)
-                        rules = np.full(n, -1, np.int32)
-                        for _, rfut, a, b, cn in issued:
-                            v = vals[vi]
-                            vi += 1
-                            rv = None
-                            if rfut is not None:
-                                rv = vals[vi]
-                                vi += 1
-                            if v is None:
-                                allow[a:b] = False
-                            else:
-                                allow[a:b] = np.asarray(v)[:cn]
-                                if rv is not None:
-                                    rules[a:b] = np.asarray(rv)[:cn]
-                        rt.drained()
-                        self.fast_log.log_batch(
-                            getattr(engine, "proto", "r2d2"), n,
-                            int(n - allow.sum()),
+                        allow, rules = self._chunk_values(
+                            issued, r[2], chunk
                         )
-                        self.vec_batches += 1
-                        self.vec_entries += n
-                        metrics.ProxyBatches.inc()
-                        self._send_vec_frames(
-                            sends, allow,
-                            getattr(engine, "DENY_INJECT", None),
-                        )
-                        self.tracer.finish_round(
-                            rt, [self._batch_desc(s[6], s[0]) for s in sends]
-                        )
-                        if engine is not None and sends:
-                            self._record_vec_round(
-                                engine,
-                                np.concatenate([s[2] for s in sends]),
-                                allow, rules,
+                        if r[0] == "mat":
+                            _, _, _, items, rt, engine, ids, lengths = r
+                            self._answer_mat_round(
+                                items, engine, ids, lengths, allow, rules,
+                                rt,
+                            )
+                        else:
+                            _, _, n, sends, rt, engine = r
+                            self._answer_vec_round(
+                                sends, n, rt, engine, allow, rules
                             )
                     elif r[0] == "entry2":
                         # Runs even when deposed: finish() drains engine
@@ -5993,6 +5979,54 @@ class VerdictService:
                     log.exception("completion failed")
                 finally:
                     cur._disp_round = None
+
+    def _answer_vec_round(self, sends, n, rt, engine, allow,
+                          rules) -> None:
+        """Answer a round of DATA batches from its verdict arrays: one
+        VERDICT_BATCH frame per wire batch."""
+        rt.drained()
+        self.fast_log.log_batch(
+            getattr(engine, "proto", "r2d2"), n, int(n - allow.sum())
+        )
+        self.vec_batches += 1
+        self.vec_entries += n
+        metrics.ProxyBatches.inc()
+        self._send_vec_frames(
+            sends, allow, getattr(engine, "DENY_INJECT", None)
+        )
+        if not self._round_thread_suppressed():
+            self.tracer.finish_round(
+                rt, [self._batch_desc(s[6], s[0]) for s in sends]
+            )
+            if sends:
+                self._record_vec_round(
+                    engine, np.concatenate([s[2] for s in sends]),
+                    allow, rules,
+                )
+
+    @staticmethod
+    def _chunk_values(issued: list, n: int, vals: list):
+        """A round's (allow, rule) arrays from its chunks' read-back
+        values: per chunk the allow value, then (with attribution) the
+        rule value; a failed allow readback (None) denies and
+        unattributes its chunk, a failed rule readback unattributes."""
+        allow = np.empty(n, bool)
+        rules = np.full(n, -1, np.int32)
+        k = 0
+        for _, rfut, a, b, cn in issued:
+            v = vals[k]
+            k += 1
+            rv = None
+            if rfut is not None:
+                rv = vals[k]
+                k += 1
+            if v is None:
+                allow[a:b] = False
+            else:
+                allow[a:b] = np.asarray(v)[:cn]
+                if rv is not None:
+                    rules[a:b] = np.asarray(rv)[:cn]
+        return allow, rules
 
     _ERR_ROW = np.frombuffer(b"ERROR\r\n", np.uint8)
 

@@ -253,6 +253,15 @@ ProxyVerdicts = registry.counter(
 ProxyBatches = registry.counter(
     "proxy_batches_total", "Device verdict batches dispatched"
 )
+VerdictWholeRounds = registry.counter(
+    "verdict_whole_rounds_total",
+    "Sidecar rounds of complete-frame matrix batches judged as one whole "
+    "round (one table gather, one device issue, one answer per client)",
+)
+VerdictWholeEntries = registry.counter(
+    "verdict_whole_entries_total",
+    "Entries of the rounds counted by verdict_whole_rounds_total",
+)
 KvstoreDegraded = registry.gauge(
     "kvstore_degraded",
     "1 while the cluster store is fenced/unreachable and the agent "
