@@ -143,8 +143,11 @@ def _accepts(state: jax.Array, mask: jax.Array) -> jax.Array:
     )
 
 
+_BLOCK = 8  # byte positions per trip of the scan loop
+
+
 def _dfa_scan(dfa: DeviceDfa, data, span_start, span_end):
-    f = data.shape[0]
+    f, length = data.shape
     r, s, c = dfa.n_patterns, dfa.n_states, dfa.n_classes
 
     state0 = jnp.broadcast_to(dfa.start_1h[None, :, :], (f, r, s)).astype(
@@ -152,13 +155,19 @@ def _dfa_scan(dfa: DeviceDfa, data, span_start, span_end):
     )
     accepted0 = _accepts(state0, dfa.accept_mask)
 
-    data_t = data.T  # [L, F]
+    # Positions past every span change no state and re-OR an accept bit
+    # already ORed, so the loop stops after the last block any span
+    # reaches: 3 blocks for a round of 24-byte frames in 256-byte rows.
+    # The bound is a traced scalar, so one executable serves every round.
+    span_end = jnp.minimum(jnp.asarray(span_end, jnp.int32), length)
+    hi = jnp.max(span_end, initial=0)
+    n_blk = (hi + _BLOCK - 1) // _BLOCK
+    pad = -length % _BLOCK  # zero rows at t >= length are never active
+    data_t = jnp.pad(data.T, ((0, pad), (0, 0)))  # [L + pad, F]
 
     iota_s = jnp.arange(s, dtype=jnp.int32)
 
-    def step(carry, inputs):
-        state, accepted = carry
-        byte_col, t = inputs  # [F]
+    def step(state, accepted, byte_col, t):
         cls1h = byte_class_onehot(dfa, byte_col)  # [F, C]
         # Row select: row[f, r, c] = delta_id[r, cur_state(f,r), c]
         # — one-hot state × integer table, O(S·C) MACs per (f, r).
@@ -176,15 +185,20 @@ def _dfa_scan(dfa: DeviceDfa, data, span_start, span_end):
         active = (t >= span_start) & (t < span_end)  # [F]
         state = jnp.where(active[:, None, None], nxt, state)
         accepted = accepted | _accepts(state, dfa.accept_mask)
-        return (state, accepted), None
+        return state, accepted
 
-    length = data.shape[1]
-    ts = jnp.arange(length, dtype=jnp.int32)
-    # unroll: each step is a handful of SMALL kernels (the per-policy
-    # tables are tiny), so an un-unrolled scan is launch-latency-bound;
-    # unrolling lets XLA fuse across byte positions.
-    (state, accepted), _ = jax.lax.scan(
-        step, (state0, accepted0), (data_t, ts), unroll=8
+    def block(k, carry):
+        # Each step is a handful of SMALL kernels (the per-policy tables
+        # are tiny), so one step a trip is launch-latency-bound; the
+        # unrolled block lets XLA fuse across byte positions.
+        t0 = k * _BLOCK
+        cols = jax.lax.dynamic_slice_in_dim(data_t, t0, _BLOCK)
+        for i in range(_BLOCK):
+            carry = step(*carry, cols[i], t0 + i)
+        return carry
+
+    state, accepted = jax.lax.fori_loop(
+        0, n_blk, block, (state0, accepted0)
     )
     final_acc = _accepts(state, dfa.accept_final_mask)
     return accepted | final_acc  # [F, R] bool

@@ -109,6 +109,83 @@ def test_dfa_matches_nfa_spans():
     np.testing.assert_array_equal(got, want)
 
 
+def _span_case(case):
+    """(data, span_start, span_end) for one span shape of the bounded
+    scan: the loop stops after the last 8-byte block any span reaches."""
+    rng = random.Random(case)
+    fill = b"abcdxyz/._0123456789GETPOSTHEAD@ \r\n"
+    width = {"width_20": 20, "width_37": 37}.get(case, 256)
+    if case.startswith("width_"):
+        # Rows filled to the width, so the last (partial) block is live.
+        subjects = [
+            bytes(rng.choice(fill) for _ in range(rng.randrange(0, width + 1)))
+            for _ in range(40)
+        ] + [bytes(rng.choice(fill) for _ in range(width))] * 2
+    else:
+        subjects = list(SUBJECTS)
+    data, lengths = _pad(subjects, width)
+    f = len(subjects)
+    start = np.zeros((f,), np.int32)
+    end = np.zeros((f,), np.int32)
+    for i in range(f):
+        a = rng.randrange(0, int(lengths[i]) + 1)
+        b = rng.randrange(0, int(lengths[i]) + 1)
+        start[i], end[i] = min(a, b), max(a, b)
+    if case == "all_empty":
+        end[:] = start
+    elif case == "start_after_end":
+        start, end = np.maximum(end, 1), np.minimum(start, end) - 1
+    elif case == "one_reaches_width":
+        row = bytes(rng.choice(fill) for _ in range(width))
+        data[-1] = np.frombuffer(row, np.uint8)
+        start[-1], end[-1] = 0, width
+    elif case == "width_37":
+        start[-1], end[-1] = 33, width  # only the last, 5-byte block
+    return data, start, end
+
+
+@pytest.mark.parametrize(
+    "case",
+    [
+        "all_empty",
+        "one_reaches_width",
+        "width_20",
+        "width_37",
+        "start_after_end",
+        "short_frames_at_256",
+    ],
+)
+def test_bounded_scan_matches_nfa_spans(case):
+    """The DFA scan runs only the blocks the round's longest span
+    reaches; the dense NFA scans every byte.  They must agree."""
+    nfa = device_nfa(compile_patterns(PATTERNS))
+    dfa = device_dfa(compile_pattern_dfas(PATTERNS))
+    data, start, end = _span_case(case)
+    want = np.asarray(nfa_search_spans(nfa, data, start, end))
+    got = np.asarray(dfa_search_spans(dfa, data, start, end))
+    np.testing.assert_array_equal(got, want)
+
+
+def test_bounded_scan_one_executable():
+    """Rounds whose longest span ends at byte 3, 22 and 256 of one
+    (F, 256) shape share one executable: the bound is traced, not a
+    shape, so no round compiles."""
+    nfa = device_nfa(compile_patterns(PATTERNS))
+    dfa = device_dfa(compile_pattern_dfas(PATTERNS))
+    data, lengths = _pad(SUBJECTS * 2, 256)
+    data[-1] = ord("a")
+    start = np.zeros_like(lengths)
+    sizes = []
+    for hi in (3, 22, 256):
+        end = np.minimum(lengths, hi)
+        end[-1] = hi
+        got = np.asarray(dfa_search_spans(dfa, data, start, end))
+        sizes.append(dfa_search_spans._cache_size())
+        want = np.asarray(nfa_search_spans(nfa, data, start, end))
+        np.testing.assert_array_equal(got, want)
+    assert sizes[0] == sizes[1] == sizes[2], sizes
+
+
 def test_dfa_fuzz_random_bytes():
     rng = random.Random(9)
     subjects = []

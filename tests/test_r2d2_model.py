@@ -12,7 +12,11 @@ import numpy as np
 import pytest
 
 from cilium_tpu.models.base import ConstVerdict
-from cilium_tpu.models.r2d2 import build_r2d2_model, r2d2_verdicts
+from cilium_tpu.models.r2d2 import (
+    build_r2d2_model,
+    r2d2_verdicts,
+    r2d2_verdicts_attr,
+)
 from cilium_tpu.proxylib import (
     DROP,
     MORE,
@@ -139,6 +143,95 @@ def test_r2d2_model_bit_identical_fuzz():
                     f"policy={policy_name} src={src_id} msg={m!r}: "
                     f"device={allows[i]} oracle={expected}"
                 )
+
+
+def _benchmark_frames(rng, n):
+    """The five frames of the benchmark's r2d2 traffic, CRLF stripped."""
+    out = []
+    for _ in range(n):
+        k = rng.randrange(0, 997)
+        out.append(rng.choice([
+            f"READ /public/f{k}.txt", f"READ /private/f{k}",
+            f"WRITE /public/f{k}.txt", "HALT", "RESET",
+        ]).encode())
+    return out
+
+
+def test_r2d2_model_full_width_frames():
+    """Short frames in 256-byte rows beside one frame whose file span
+    ends in the row's last 8-byte block: the automaton scan stops after
+    the round's longest span, so both the short and the long round must
+    match the oracle."""
+    rng = random.Random(28)
+    mod = open_module([], True)
+    ins = find_instance(mod)
+    ins.policy_update([_policy(n, r) for n, r in POLICIES.items()])
+    width = 256
+    short = _benchmark_frames(rng, 40) + [b"WRITE /public/f996.txt"]
+    long_file = b"/public/" + b"d/" * ((width - 19) // 2) + b"x.txt"
+    long_msg = b"READ " + long_file.ljust(width - 7, b"t")
+    assert len(long_msg) + 2 == width
+    for msgs in (short, short + [long_msg]):
+        f = len(msgs)
+        data = np.zeros((f, width), dtype=np.uint8)
+        lengths = np.zeros((f,), dtype=np.int32)
+        for i, m in enumerate(msgs):
+            framed = m + b"\r\n"
+            data[i, : len(framed)] = np.frombuffer(framed, dtype=np.uint8)
+            lengths[i] = len(framed)
+        for policy_name in POLICIES:
+            policy = ins.policy_map().get(policy_name)
+            model = build_r2d2_model(policy, ingress=True, port=80)
+            if isinstance(model, ConstVerdict):
+                continue
+            for src_id in (1, 5, 7):
+                remotes = np.full((f,), src_id, dtype=np.int32)
+                complete, msg_len, allow = r2d2_verdicts(
+                    model, data, lengths, remotes
+                )
+                assert np.asarray(complete).all()
+                np.testing.assert_array_equal(np.asarray(msg_len), lengths)
+                for i, m in enumerate(msgs):
+                    expected = _oracle_verdict(mod, policy_name, src_id, m)
+                    assert bool(np.asarray(allow)[i]) == expected, (
+                        f"policy={policy_name} src={src_id} msg={m!r}"
+                    )
+
+
+def test_r2d2_attr_one_executable():
+    """Rounds whose longest file span ends at byte 3, 22 and 256 of one
+    (F, 256) shape run one attributed executable: the scan's bound is
+    traced, so no round compiles."""
+    mod = open_module([], True)
+    ins = find_instance(mod)
+    ins.policy_update([_policy("public-files", POLICIES["public-files"])])
+    model = build_r2d2_model(ins.policy_map()["public-files"], True, 80)
+    rounds = (
+        [b"R a\r\n", b"HALT\r\n"],
+        [b"WRITE /public/f996.txt\r\n", b"HALT\r\n"],
+        [b"WRITE /public/f996.txt\r\n", b"READ /public/" + b"a" * 243],
+    )
+    f, width = 16, 256
+    sizes = []
+    for frames in rounds:
+        data = np.zeros((f, width), dtype=np.uint8)
+        lengths = np.zeros((f,), dtype=np.int32)
+        for i, m in enumerate(frames):
+            data[i, : len(m)] = np.frombuffer(m, dtype=np.uint8)
+            lengths[i] = len(m)
+        complete, _, allow, rule = r2d2_verdicts_attr(
+            model, data, lengths, np.ones((f,), np.int32)
+        )
+        sizes.append(r2d2_verdicts_attr._cache_size())
+        # allow is computed for a partial row too: it reads the scan.
+        want = [b" /public/" in m for m in frames]
+        assert np.asarray(allow)[: len(frames)].tolist() == want
+        assert np.asarray(rule)[: len(frames)].tolist() == [
+            0 if w else -1 for w in want
+        ]
+    # The last round's partial row spans to byte 256 and is not complete.
+    assert not bool(np.asarray(complete)[1])
+    assert sizes[0] == sizes[1] == sizes[2], sizes
 
 
 def test_r2d2_model_port_cascade():
