@@ -1535,8 +1535,7 @@ def bench_latency(colocated: bool = False, null_seam: bool = False):
     print(
         f"bench latency{' (colocated)' if colocated else ''}: "
         f"oracle p50={out['oracle_p50_ms']:.4f}ms "
-        f"device_rtt={out['device_rtt_ms']:.1f}ms "
-        f"dispatch={out['dispatch_mode']}",
+        f"device_rtt={out['device_rtt_ms']:.1f}ms",
         file=sys.stderr,
     )
     for r in out["rates"]:
@@ -2893,8 +2892,7 @@ def bench_restart_blackout():
 
     def mk_cfg():
         return DaemonConfig(
-            batch_timeout_ms=0.0, batch_flows=256,
-            dispatch_mode="eager", flow_cache=True,
+            batch_timeout_ms=0.0, batch_flows=256, flow_cache=True,
         )
 
     svc = VerdictService(path, mk_cfg()).start()
@@ -3320,8 +3318,7 @@ def bench_mesh_degraded():
     path = "/tmp/cilium_tpu_bench_mesh_degraded.sock"
     inst_mod.reset_module_registry()
     cfg = DaemonConfig(
-        batch_timeout_ms=0.0, batch_flows=256, dispatch_mode="jit",
-        mesh="on", mesh_rule_shards=2,
+        batch_timeout_ms=0.0, batch_flows=256, mesh="on", mesh_rule_shards=2,
         mesh_reprobe_interval_s=0.05,
         device_reprobe_interval_s=1e9,
     )
@@ -3602,7 +3599,6 @@ def run_one(which: str) -> None:
             rtt_multiples_p99=round(r1m.p99_ms / rtt, 2),
             p99_ms_at_100k=round(r100k.p99_ms, 2),
             rtt_multiples_p99_at_100k=round(r100k.p99_ms / rtt, 2),
-            dispatch_mode=lat["dispatch_mode"],
         )
     elif which == "latency_colocated":
         # Device term removed (CPU-backed verdict models): measures the
@@ -3643,7 +3639,6 @@ def run_one(which: str) -> None:
             p90_ms=round(r100k.p90_ms, 3),
             p99_ms=round(r100k.p99_ms, 3),
             achieved_rate=round(r100k.achieved_rate),
-            dispatch_mode=out["dispatch_mode"],
             release_late_p50_ms=round(r100k.release_late_p50_ms, 3),
             release_late_p99_ms=round(r100k.release_late_p99_ms, 3),
             p99_runs_100k=out["seam_p99_runs"],

@@ -549,8 +549,6 @@ def _dump_failure(served: Served, watch: CompileWatch, t_window: float):
             f"on {thread}")
     st = served.service.status()
     log(f"FAILED RUN containment: {json.dumps(st['containment'])}")
-    log(f"FAILED RUN dispatch mode: {st['dispatch_mode']} "
-        f"{json.dumps(st['dispatch_probe_ms'])}")
     for ev in served.service.ledger.events(n=10_000):
         log(f"FAILED RUN ledger: {json.dumps(ev, default=str)[:400]}")
     log(f"FAILED RUN stages: {json.dumps(st['latency']['stages'])}")
@@ -619,8 +617,6 @@ def check_device_path(st: dict, counts: dict, label: str) -> None:
 
 def report(served: Served, label: str, counts: dict) -> dict:
     st = served.status()
-    log(f"[{label}] dispatch mode: {st['dispatch_mode']} "
-        f"probe_ms={json.dumps(st['dispatch_probe_ms'])}")
     led = served.client.ledger(n=10_000)
     for ev in led["compiles"]:
         log(f"[{label}] compile: cause={ev.get('cause')} "

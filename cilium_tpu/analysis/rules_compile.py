@@ -14,7 +14,7 @@ graph, the same engine R2/R4 ride):
 
 - **Reachability.**  Compile-class calls (``jax.jit``, ``prewarm``,
   ``build_*_model*``, ``compile_automaton``, ``_make_engine`` /
-  ``_build_engine``, ``_measure_dispatch_mode``, ``lower``/
+  ``_build_engine``, ``lower``/
   ``eval_shape``/``.compile``) reachable from the dispatch/service hot
   loops of the hot modules (dispatch.py / service.py / shm.py roots:
   the round entry ``_process*``, the vec/mat/slow runners, the
@@ -56,7 +56,7 @@ _DISPATCH_ROOTS = {
 
 _COMPILE_NAMES = {
     "jit", "pjit", "prewarm", "compile_automaton",
-    "_make_engine", "_build_engine", "_measure_dispatch_mode",
+    "_make_engine", "_build_engine",
     "lower", "eval_shape", "compile", "trace",
 }
 _COMPILE_RE = re.compile(r"^build_\w*model\w*$")

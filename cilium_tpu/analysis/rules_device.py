@@ -573,7 +573,7 @@ def check_r11(files):
 
 # Dispatch boundaries whose array arguments must carry bucketed batch
 # axes (the service's jit seams).
-_DISPATCH_NAMES = {"_model_call", "_model_call_attr", "_gathered_call"}
+_DISPATCH_NAMES = {"_model_call_attr", "_gathered_call"}
 _JIT_WRAPPERS = {"jit", "pjit", "_jit_for"}
 
 _ALLOC_NAMES = {"zeros", "empty", "ones", "full"}

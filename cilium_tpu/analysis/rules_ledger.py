@@ -13,7 +13,7 @@ under-counts.
 Detection (interprocedural, same import-resolved call graph R2/R4/R12
 ride): compile-class calls (``jax.jit``/``pjit``, ``prewarm``,
 ``compile_automaton``, ``_make_engine``/``_build_engine``,
-``_measure_dispatch_mode``, ``eval_shape``, ``build_*model*`` /
+``eval_shape``, ``build_*model*`` /
 ``mesh_*model*`` builders) in the hot modules, reachable from the R12
 dispatch roots PLUS the policy-builder roots (swap/rebind/mesh-ladder
 — the off-path compile sites R12 deliberately sanctions are exactly
@@ -53,8 +53,7 @@ _LEDGER_HOT_BASENAMES = _HOT_BASENAMES | {"engines.py"}
 # ``re.compile`` / ``str.lower`` in builder helpers).
 _COMPILE_NAMES = {
     "jit", "pjit", "prewarm", "compile_automaton",
-    "_make_engine", "_build_engine", "_measure_dispatch_mode",
-    "eval_shape",
+    "_make_engine", "_build_engine", "eval_shape",
 }
 _COMPILE_RE = re.compile(r"^(build|mesh)_\w*model\w*$")
 

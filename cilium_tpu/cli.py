@@ -445,8 +445,7 @@ def cmd_sidecar_status(args):
         else "Ok"
     )
     print(f"{args.address}: {health}")
-    print(f"connections: {st['connections']}  engines: {st['engines']}  "
-          f"dispatch={st['dispatch_mode']}")
+    print(f"connections: {st['connections']}  engines: {st['engines']}")
     print(f"verdicts: {st['requests']} requests, {st['denied']} denied, "
           f"{st['vec_entries']} vectorized")
     print(f"queue: depth={disp.get('queue_depth', 0)} "

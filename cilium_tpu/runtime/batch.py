@@ -124,6 +124,12 @@ class R2d2BatchEngine(InvariantClaimEngine):
         # pump on the PLAIN model call — no argmax, no extra readback
         # (the flow_observe_overhead bench's disabled baseline).
         self.attr_enabled = attr_enabled
+        # Optional service-owned launch, the l7 engines' judge_dispatch
+        # contract: (data, lengths, remotes) -> (complete, msg_len,
+        # allow, rule-or-None) through the service's shape-keyed jit
+        # caches, its compile ledger and its mesh demotion rung.  None
+        # (an engine built outside a service) calls the model itself.
+        self.judge_dispatch = None
         # Per-flow retained-bytes cap: a flow that buffers more than
         # this without a frame delimiter is dropped with a typed
         # protocol-error (bounded retained-data contract; the streaming
@@ -326,16 +332,18 @@ class R2d2BatchEngine(InvariantClaimEngine):
             lengths[i] = n
             remotes[i] = st.remote_id
 
-        attr = (
-            getattr(self.model, "verdicts_attr", None)
-            if self.attr_enabled else None
-        )
-        if attr is not None:
-            complete, msg_len, allow, rule = attr(data, lengths, remotes)
-            rule = np.asarray(rule)
+        if self.judge_dispatch is not None:
+            complete, msg_len, allow, rule = self.judge_dispatch(
+                data, lengths, remotes
+            )
+        elif self.attr_enabled and hasattr(self.model, "verdicts_attr"):
+            complete, msg_len, allow, rule = self.model.verdicts_attr(
+                data, lengths, remotes
+            )
         else:
             complete, msg_len, allow = self.model(data, lengths, remotes)
             rule = None
+        rule = np.asarray(rule) if rule is not None else None
         complete = np.asarray(complete)
         msg_len = np.asarray(msg_len)
         allow = np.asarray(allow)

@@ -51,8 +51,6 @@ class NullVerdictServer:
     floor (socket + framing + host scheduler); the seam's
     architecture-attributable added latency is seam_p99 − null_p99."""
 
-    dispatch_mode_chosen = "null"
-
     class _Zero:
         batches = entries = fill_dispatches = deadline_dispatches = 0
 
@@ -217,7 +215,6 @@ class LatencyBench:
         client_timeout_ms: float = 0.2,
         policy=None,
         verdict_device: str = "default",
-        dispatch_mode: str = "auto",
         seam_probe: bool = False,
         wire_mode: str = "matrix",  # matrix (pre-padded) | blob (compact)
         null_seam: bool = False,
@@ -258,7 +255,6 @@ class LatencyBench:
                 batch_timeout_ms=batch_timeout_ms,
                 batch_width=64,
                 verdict_device=verdict_device,
-                dispatch_mode=dispatch_mode,
                 seam_probe=seam_probe,
             )
             self.service = VerdictService(socket_path, cfg).start()
@@ -549,7 +545,6 @@ def run_paired_colocated(
         "oracle_p50_ms": oracle_p50,
         "oracle_p99_ms": oracle_p99,
         "os_noise": os_noise,
-        "dispatch_mode": seam.service.dispatch_mode_chosen,
         # What the seam client actually rode (mode + ring/doorbell/
         # fallback counters) — a result claiming "shm" with a session
         # that silently demoted to the socket must be readable as such.
@@ -736,7 +731,6 @@ def run(
             "device_rtt_ms": rtt_ms,
             "uplink_mbps": uplink_mbps,
             "colocated": colocated,
-            "dispatch_mode": bench.service.dispatch_mode_chosen,
             "os_noise": os_noise,
             "p99_runs": p99_runs,
             "rates": results,

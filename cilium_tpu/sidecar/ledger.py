@@ -6,8 +6,9 @@ module answers the two remaining economic questions that gate ROADMAP
 items 4 and 5:
 
 - **Why did a compile happen?**  Every executable-producing site —
-  the service's shape-keyed jit caches (``_jit_for`` /
-  ``_model_call`` / ``_model_call_attr`` / ``_gathered_call``),
+  the service's shape-keyed jit caches (``_jit_for`` behind the
+  launcher ``_model_call_attr`` and its gather twin
+  ``_gathered_call``),
   prewarm, the policy-builder's swap/rebind/mesh-reshape/re-promotion
   rebuilds, and the daemon-side engine builders — routes through ONE
   choke point, :meth:`DeviceLedger.record_compile`, which stamps the

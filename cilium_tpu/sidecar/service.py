@@ -475,19 +475,6 @@ class VerdictService:
         # row and gather paths): a churn rebuild whose tables land in
         # the same buckets skips its warm launches entirely.
         self._prewarmed_shapes: dict = {}
-        # Dispatch mode: 'eager'/'jit' honored as configured; 'auto' is
-        # resolved by measurement at the first engine prewarm (guarded
-        # by _dispatch_lock: concurrent first binds must not measure
-        # twice or observe a mid-measurement mode flip).
-        self._use_jit = self.config.dispatch_mode == "jit"
-        self._dispatch_resolved = self.config.dispatch_mode != "auto"
-        self._dispatch_lock = threading.Lock()
-        self.dispatch_mode_chosen = (
-            self.config.dispatch_mode
-            if self._dispatch_resolved else None
-        )
-        # Both burst timings of the 'auto' probe; None when not probed.
-        self.dispatch_probe_ms: dict | None = None
         self._exec_device = None
         if self.config.verdict_device == "cpu":
             import jax
@@ -1153,8 +1140,6 @@ class VerdictService:
                 "dead": list(self._dead_sessions),
                 "fair_share": self._share_val,
             },
-            "dispatch_mode": self.dispatch_mode_chosen,
-            "dispatch_probe_ms": self.dispatch_probe_ms,
             # Multi-chip mesh rung: layout + demotion state; None when
             # multi-chip serving is off or no engine has resolved it.
             "mesh": self._mesh_status(),
@@ -1807,8 +1792,8 @@ class VerdictService:
             data[i, : len(row)] = row
             lens[i] = len(row)
             rems[i] = rem
-        out = self._model_call(model, data, lens, rems)
-        allow = np.asarray(out[-1])[: len(cases)]
+        out = self._model_call_attr(model, data, lens, rems)
+        allow = np.asarray(out[2])[: len(cases)]
         for i, (cmd, f, rem) in enumerate(cases):
             host = policy is not None and policy.matches(
                 ingress, port, rem, R2d2RequestData(cmd, f)
@@ -1871,8 +1856,8 @@ class VerdictService:
             data[i, : len(row)] = row
             lens[i] = len(row)
             rems[i] = rem
-        out = self._model_call(model, data, lens, rems)
-        allow = np.asarray(out[-1])[: len(cases)]
+        out = self._model_call_attr(model, data, lens, rems)
+        allow = np.asarray(out[2])[: len(cases)]
         for i, (frame, rem) in enumerate(cases):
             name = parse_dns_query(frame)
             req = DnsRequestData(
@@ -2434,25 +2419,46 @@ class VerdictService:
         the outgoing generation); the ledger uses its model's shape key
         to classify the rebuild as vocab churn vs. new-shape churn."""
         t0 = time.perf_counter()
-        if proto == "r2d2":
-            from ..models.r2d2 import build_r2d2_model
+        if proto in ("r2d2", "dns"):
+            if proto == "r2d2":
+                from ..models.r2d2 import build_r2d2_model
 
-            if self.config.seam_probe:
-                from ..models.base import SeamProbe
+                if self.config.seam_probe:
+                    from ..models.base import SeamProbe
 
-                model = SeamProbe()
+                    model = SeamProbe()
+                else:
+                    mesh = self._serving_mesh()
+                    if mesh is not None:
+                        # Multi-chip build: rule rows split-balanced and
+                        # padded across RULE_AXIS, single-chip fallback
+                        # compiled alongside (the device-loss rung).
+                        from ..parallel.rulesharding import (
+                            mesh_r2d2_model,
+                        )
+
+                        model = mesh_r2d2_model(policy, ingress, port,
+                                                mesh)
+                    else:
+                        model = build_r2d2_model(policy, ingress, port)
+                cls = R2d2BatchEngine
             else:
+                # The DNS engine rung: same scalar contract as r2d2 (the
+                # flagship FlowState machinery, subclassed with the
+                # length-prefix framing hooks), mesh-aware build with
+                # the single-chip fallback compiled alongside.
+                from ..models.dns import build_dns_model
+                from ..runtime.dnsengine import DnsBatchEngine
+
                 mesh = self._serving_mesh()
                 if mesh is not None:
-                    # Multi-chip build: rule rows split-balanced and
-                    # padded across RULE_AXIS, single-chip fallback
-                    # compiled alongside (the device-loss rung).
-                    from ..parallel.rulesharding import mesh_r2d2_model
+                    from ..parallel.rulesharding import mesh_dns_model
 
-                    model = mesh_r2d2_model(policy, ingress, port, mesh)
+                    model = mesh_dns_model(policy, ingress, port, mesh)
                 else:
-                    model = build_r2d2_model(policy, ingress, port)
-            eng = R2d2BatchEngine(
+                    model = build_dns_model(policy, ingress, port)
+                cls = DnsBatchEngine
+            eng = cls(
                 model,
                 capacity=self.config.batch_flows,
                 width=self.config.batch_width,
@@ -2461,31 +2467,11 @@ class VerdictService:
                 attr_enabled=self._flow_observe,
                 min_rows=self._min_bucket,
             )
-            self._finish_engine_build(eng, proto, prior, t0)
-            return eng
-        if proto == "dns":
-            # The DNS engine rung: same scalar contract as r2d2 (the
-            # flagship FlowState machinery, subclassed with the
-            # length-prefix framing hooks), mesh-aware build with the
-            # single-chip fallback compiled alongside.
-            from ..models.dns import build_dns_model
-            from ..runtime.dnsengine import DnsBatchEngine
-
-            mesh = self._serving_mesh()
-            if mesh is not None:
-                from ..parallel.rulesharding import mesh_dns_model
-
-                model = mesh_dns_model(policy, ingress, port, mesh)
-            else:
-                model = build_dns_model(policy, ingress, port)
-            eng = DnsBatchEngine(
-                model,
-                capacity=self.config.batch_flows,
-                width=self.config.batch_width,
-                logger=ins.access_logger,
-                max_buffer=self.config.max_flow_buffer,
-                attr_enabled=self._flow_observe,
-                min_rows=self._min_bucket,
+            # The pump launches through the service, like every other
+            # round path: one executable per shape, ledgered, mesh-rung
+            # guarded (set before the prewarm below).
+            eng.judge_dispatch = functools.partial(
+                self._engine_judge_dispatch, eng
             )
             self._finish_engine_build(eng, proto, prior, t0)
             return eng
@@ -2619,8 +2605,9 @@ class VerdictService:
         return None
 
     def _engine_judge_dispatch(self, eng, data, lengths, remotes):
-        """(complete, len, allow, rule-or-None) for an l7 engine's
-        judge step — reads eng.model at CALL time so a mesh demotion's
+        """(complete, len, allow, rule-or-None) for an engine's own
+        device step (the r2d2/DNS pump, an l7 judge) through the
+        launcher — reads eng.model at CALL time so a mesh demotion's
         pointer flip (or an epoch swap) takes effect mid-stream."""
         return self._model_call_attr(eng.model, data, lengths, remotes)
 
@@ -3617,10 +3604,10 @@ class VerdictService:
             self._shed_item(it, "stall")
 
     def _device_probe(self) -> None:
-        """One real device round (used by quarantine re-probes): prefer
-        an r2d2 engine's own model; fall back to a bare device op when
-        no row-shaped model exists.  Raises/hangs exactly when the
-        device path is still unhealthy."""
+        """One real device round (used by quarantine re-probes): an
+        r2d2 engine's model through the launcher, at a prewarmed shape;
+        a bare device op when no row-shaped model exists.  Raises/hangs
+        exactly when the device path is still unhealthy."""
         with self._lock:
             eng = next(
                 (
@@ -3633,13 +3620,13 @@ class VerdictService:
         if eng is not None:
             b = self._min_bucket
             w = self.config.batch_width
-            with self._device_ctx():
-                out = eng.model(
-                    np.zeros((b, w), np.uint8),
-                    np.zeros(b, np.int32),
-                    np.zeros(b, np.int32),
-                )
-            np.asarray(out[-1])
+            out = self._model_call_attr(
+                eng.model,
+                np.zeros((b, w), np.uint8),
+                np.zeros(b, np.int32),
+                np.zeros(b, np.int32),
+            )
+            np.asarray(out[2])
             return
         import jax
         import jax.numpy as jnp
@@ -5099,101 +5086,35 @@ class VerdictService:
             "reshape_window_ms": self.mesh_reshape_window_ms,
         }
 
-    def _model_call(self, model, data, lens, remotes, use_jit=None):
-        """One device dispatch per batch.  The mode is a MEASURED
-        config (config.dispatch_mode): 'eager' pipelines per-op async
-        dispatch, 'jit' fuses the model into one launch; 'auto' times
-        both at first prewarm (the service's real pattern: async issue
-        + one batched readback) and keeps the faster.  ``use_jit``
-        overrides the resolved mode (used by the measurement itself so
-        it never mutates shared state mid-flight)."""
-        uj = self._use_jit if use_jit is None else use_jit
-
-        def call(m):
-            with self._device_ctx():
-                if uj and not isinstance(m, ConstVerdict):
-                    fn = self._jit_for(
-                        self._jit_cache, m, m.__call__,
-                        arg_fn=_call_model,
-                    )
-                    return fn(data, lens, remotes)
-                return m(data, lens, remotes)
-
-        return self._mesh_guarded(self._live_model(model), call)
-
     def _model_call_attr(self, model, data, lens, remotes):
-        """_model_call plus device-side rule attribution: returns
-        (complete, msg_len, allow, rule-or-None).  The rule index rides
-        the SAME fused computation (an argmax over the hit matrix the
-        verdict reduction already builds — no extra device pass; on a
-        mesh, the shard-local argmax plus the cross-shard min-index
-        reduction, still one device round); when flow observability is
-        off or the model has no attributed variant, this degrades to
-        the plain call with rule None."""
+        """THE launch of a verdict model on the device: one shape-keyed,
+        ledgered jit executable per batch (``_jit_for``), behind the
+        mesh demotion rung.  Returns (complete, msg_len, allow,
+        rule-or-None).  The rule index rides the SAME fused computation
+        (an argmax over the hit matrix the verdict reduction already
+        builds — no extra device pass; on a mesh, the shard-local
+        argmax plus the cross-shard min-index reduction, still one
+        device round); when flow observability is off or the model has
+        no attributed variant, the plain model launches instead and the
+        rule is None."""
         model = self._live_model(model)
-        fn = (
-            getattr(model, "verdicts_attr", None)
-            if self._flow_observe else None
-        )
-        if fn is None:
-            c, m, a = self._model_call(model, data, lens, remotes)
-            return c, m, a, None
-        uj = self._use_jit
+        attr = self._flow_observe and hasattr(model, "verdicts_attr")
 
         def call(m):
             with self._device_ctx():
-                if uj and not isinstance(m, ConstVerdict):
-                    jfn = self._jit_for(
+                if attr:
+                    fn = self._jit_for(
                         self._jit_attr, m,
                         lambda d, ln, r: m.verdicts_attr(d, ln, r),
                         arg_fn=_call_model_attr,
                     )
-                    return jfn(data, lens, remotes)
-                return m.verdicts_attr(data, lens, remotes)
+                    return fn(data, lens, remotes)
+                fn = self._jit_for(
+                    self._jit_cache, m, m.__call__, arg_fn=_call_model,
+                )
+                return (*fn(data, lens, remotes), None)
 
         return self._mesh_guarded(model, call)
-
-    def _measure_dispatch_mode(self, engine) -> None:
-        """Resolve dispatch_mode='auto': time the service's ACTUAL
-        per-round pattern — issue N batches without blocking, then ONE
-        batched ``jax.device_get`` — in each mode and keep the faster.
-        (Timing ``block_until_ready`` instead would measure N serial
-        readbacks and mask the dispatch-side difference: on a
-        high-latency link each jit launch blocks ~1 RTT while eager op
-        dispatch streams asynchronously.)"""
-        import time as _time
-
-        import jax
-
-        b = self._min_bucket
-        width = self.config.batch_width
-        data = np.zeros((b, width), np.uint8)
-        lens = np.zeros(b, np.int32)
-        rem = np.zeros(b, np.int32)
-
-        def burst(uj: bool) -> float:
-            outs = [
-                self._model_call(engine.model, data, lens, rem, use_jit=uj)[-1]
-                for _ in range(8)
-            ]
-            jax.device_get(outs)  # warm (compile / first launch)
-            t0 = _time.perf_counter()
-            outs = [
-                self._model_call(engine.model, data, lens, rem, use_jit=uj)[-1]
-                for _ in range(8)
-            ]
-            jax.device_get(outs)
-            return _time.perf_counter() - t0
-
-        t_eager = burst(False)
-        t_jit = burst(True)
-        self._use_jit = t_jit < t_eager
-        self.dispatch_mode_chosen = "jit" if self._use_jit else "eager"
-        self.dispatch_probe_ms = {"eager": t_eager * 1e3, "jit": t_jit * 1e3}
-        log.info(
-            "dispatch mode auto: eager=%.1fms jit=%.1fms -> %s",
-            t_eager * 1e3, t_jit * 1e3, self.dispatch_mode_chosen,
-        )
 
     def _model_shape_key(self, model):
         """Hashable shape signature for a shape-cacheable model, or
@@ -5257,12 +5178,6 @@ class VerdictService:
             return False
         with ledger_mod.cause_scope(ledger_mod.CAUSE_PREWARM,
                                     epoch=self.policy_epoch):
-            if not self._dispatch_resolved:
-                with self._dispatch_lock:
-                    if not self._dispatch_resolved:
-                        # lint: disable=R12 -- one-time dispatch-mode probe at the FIRST prewarm ever (double-checked): the lock exists precisely to run this measurement once; prewarm runs on reader/builder threads, never dispatch
-                        self._measure_dispatch_mode(engine)
-                        self._dispatch_resolved = True
             judge = getattr(engine, "judge_shapes", None)
             shapes = judge() if judge is not None else None
             warmed = self._prewarm_model(engine.model, shapes)
@@ -5275,9 +5190,10 @@ class VerdictService:
         return warmed
 
     def _prewarm_model(self, model, judge_shapes=None) -> bool:
-        """Warm every executable real rounds launch: the direct call at
-        each bucket plus the gather path, or — for a judge engine — the
-        direct call at each of its declared (rows, width) shapes."""
+        """Warm every executable real rounds launch: the launcher at
+        each bucket (round paths, engine pump and device probe alike)
+        plus the gather path, or — for a judge engine — the launcher at
+        each of its declared (rows, width) shapes."""
         if self._shape_key_cached(self._prewarmed_shapes, model):
             return False
         width = self.config.batch_width
@@ -5306,19 +5222,6 @@ class VerdictService:
                     np.zeros(b, np.int32),
                 )
                 np.asarray(allow)
-                if self._use_jit:
-                    # The engines' pump calls the model itself, not the
-                    # jit wrappers above (in eager mode the direct call
-                    # warmed it already).
-                    attr = (getattr(model, "verdicts_attr", None)
-                            if self._flow_observe else None)
-                    with self._device_ctx():
-                        out = (attr or model)(
-                            np.zeros((b, w), np.uint8),
-                            np.zeros(b, np.int32),
-                            np.zeros(b, np.int32),
-                        )
-                    np.asarray(out[2])
         self._mark_shape_prewarmed(model)
         return True
 
@@ -5680,14 +5583,12 @@ class VerdictService:
         return issued
 
     def _gathered_call(self, model, blob_dev, offs, lens, remotes):
-        """Dispatch gather+model as ONE jit executable — always jit,
-        regardless of the measured row-path mode: the fused
-        gather+model launch is a single dispatch on any transport,
-        while an eager gather chain pays per-op dispatch (measured
-        catastrophic — seconds per round — on the rounds 1–5
-        chip).  Returns (allow, rule-or-None); with flow observability
-        on and an attributed model, the rule argmax is fused into the
-        same executable."""
+        """The launcher's blob-window twin: the on-device row gather
+        and the model fused into ONE shape-keyed jit executable per
+        flow bucket, so a vec round uploads its payload once and never
+        builds the [n, width] rows on the host.  Returns (allow,
+        rule-or-None); with flow observability on and an attributed
+        model, the rule argmax is fused into the same executable."""
         width = self.config.batch_width
         model = self._live_model(model)
         attr = self._flow_observe and hasattr(model, "verdicts_attr")

@@ -142,10 +142,6 @@ class DaemonConfig:
     batch_flows: int = defaults.BATCH_FLOWS
     batch_width: int = defaults.BATCH_WIDTH
     batch_timeout_ms: float = defaults.BATCH_TIMEOUT_MS
-    # Device dispatch: 'eager' pipelines per-op async dispatch, 'jit'
-    # compiles one executable launch per batch, 'auto' measures both at
-    # prewarm and keeps the faster.
-    dispatch_mode: str = "auto"  # auto | eager | jit
     # 'cpu' routes verdict models to the host CPU backend (removes the
     # device-link term; used by the co-located latency proof).
     verdict_device: str = "default"  # default | cpu
@@ -388,8 +384,6 @@ class DaemonConfig:
             raise ValueError("invalid proxy port range")
         if self.batch_flows <= 0 or self.batch_width <= 0:
             raise ValueError("batch dimensions must be positive")
-        if self.dispatch_mode not in ("auto", "eager", "jit"):
-            raise ValueError(f"invalid dispatch_mode {self.dispatch_mode!r}")
         if self.verdict_device not in ("default", "cpu"):
             raise ValueError(f"invalid verdict_device {self.verdict_device!r}")
         if self.cluster_id < 0 or self.cluster_id > 255:

@@ -465,7 +465,7 @@ def test_mesh_rebinds_engine_built_while_demoted(tmp_path):
     svc = cl = None
     try:
         cfg = DaemonConfig(
-            batch_flows=64, batch_timeout_ms=0.0, dispatch_mode="jit",
+            batch_flows=64, batch_timeout_ms=0.0,
             mesh="on", mesh_rule_shards=2,
             mesh_reprobe_interval_s=0.05,
             device_reprobe_interval_s=1e9,

@@ -55,7 +55,6 @@ def _service(tmp_path, name, **cfg_kw):
     defaults = dict(
         batch_timeout_ms=2.0,
         batch_flows=256,
-        dispatch_mode="eager",
     )
     defaults.update(cfg_kw)
     cfg = DaemonConfig(**defaults)

@@ -76,7 +76,7 @@ def _start(tmp_path, name, flow_cache=True, client_cache=True,
            **cfg_kw):
     inst.reset_module_registry()
     cfg = DaemonConfig(
-        batch_flows=64, batch_width=64, dispatch_mode="eager",
+        batch_flows=64, batch_width=64,
         flow_cache=flow_cache, **cfg_kw,
     )
     svc = VerdictService(str(tmp_path / f"{name}.sock"), cfg).start()

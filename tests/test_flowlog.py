@@ -338,7 +338,6 @@ def _start_service(tmp_path, greedy: bool, **cfg_kw):
     cfg = DaemonConfig(
         batch_timeout_ms=0.0 if greedy else 2.0,
         batch_flows=256,
-        dispatch_mode="eager",
         **cfg_kw,
     )
     svc = VerdictService(
@@ -427,7 +426,11 @@ def test_observe_fault_ladder_rule_identity(tmp_path):
     row order."""
     from cilium_tpu.proxylib import instance as inst
 
-    svc, client, shim = _start_service(tmp_path, greedy=False)
+    # No re-probe while the test runs: the quarantine holds for both
+    # host-path frames (a prewarmed probe heals within milliseconds).
+    svc, client, shim = _start_service(
+        tmp_path, greedy=False, device_reprobe_interval_s=1e9
+    )
     try:
         shim.on_io(False, b"HALT\r\n")
         out = _wait_records(client, 1, path="vec")

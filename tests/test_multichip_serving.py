@@ -84,7 +84,7 @@ TRAFFIC = [
 
 def _start(tmp_path, name, **cfg_kw):
     defaults = dict(
-        batch_flows=64, dispatch_mode="jit",
+        batch_flows=64,
         mesh="on", mesh_rule_shards=2,
         device_reprobe_interval_s=1e9,
     )
@@ -312,7 +312,7 @@ def test_http_sidecar_lane_serves_sharded_and_demotes(tmp_path):
             ],
         )
         cfg = DaemonConfig(
-            batch_flows=64, batch_timeout_ms=0.0, dispatch_mode="jit",
+            batch_flows=64, batch_timeout_ms=0.0,
             mesh="on", mesh_rule_shards=2,
             device_reprobe_interval_s=1e9,
         )
@@ -904,7 +904,7 @@ def test_http_judge_lane_reshapes_and_repromotes(tmp_path):
             ],
         )
         cfg = DaemonConfig(
-            batch_flows=64, batch_timeout_ms=0.0, dispatch_mode="jit",
+            batch_flows=64, batch_timeout_ms=0.0,
             mesh="on", mesh_rule_shards=2,
             device_reprobe_interval_s=1e9,
             mesh_reprobe_interval_s=0.05,
@@ -1300,7 +1300,7 @@ def test_mesh_ladder_survives_hitless_restart(tmp_path):
     path = str(tmp_path / "handoff-mesh.sock")
     try:
         cfg_kw = dict(
-            batch_flows=64, dispatch_mode="jit", batch_timeout_ms=0.0,
+            batch_flows=64, batch_timeout_ms=0.0,
             mesh="on", mesh_rule_shards=2,
             device_reprobe_interval_s=1e9,
             mesh_reprobe_interval_s=0.05,

@@ -417,7 +417,6 @@ def test_service_end_to_end_stage_histograms_and_spans(tmp_path, greedy):
     cfg = DaemonConfig(
         batch_timeout_ms=0.0 if greedy else 2.0,
         batch_flows=256,
-        dispatch_mode="eager",
         trace_sample_every=1,
         trace_slow_ms=1e6,  # nothing is "slow" yet
     )
@@ -503,7 +502,7 @@ def test_cli_sidecar_trace(tmp_path, capsys):
 
     inst.reset_module_registry()
     cfg = DaemonConfig(
-        batch_timeout_ms=2.0, batch_flows=256, dispatch_mode="eager",
+        batch_timeout_ms=2.0, batch_flows=256,
         trace_sample_every=1, trace_slow_ms=0.0,
     )
     svc = VerdictService(str(tmp_path / "ctr.sock"), cfg).start()

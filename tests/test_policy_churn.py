@@ -77,7 +77,6 @@ def _start(tmp_path, greedy=True, name="churn", **cfg_kw):
     cfg = DaemonConfig(
         batch_timeout_ms=0.0 if greedy else 2.0,
         batch_flows=256,
-        dispatch_mode="eager",
         **cfg_kw,
     )
     svc = VerdictService(str(tmp_path / f"{name}.sock"), cfg).start()

@@ -84,7 +84,7 @@ def _policy(name="restart-pol", gen=0):
 def _cfg(**kw):
     defaults = dict(
         batch_timeout_ms=0.0, batch_flows=64, batch_width=64,
-        dispatch_mode="eager", flow_cache=True,
+        flow_cache=True,
     )
     defaults.update(kw)
     return DaemonConfig(**defaults)
@@ -492,7 +492,7 @@ from cilium_tpu.sidecar import VerdictService
 from cilium_tpu.utils.option import DaemonConfig
 
 cfg = DaemonConfig(batch_timeout_ms=0.0, batch_flows=64, batch_width=64,
-                   dispatch_mode="eager", flow_cache=True)
+                   flow_cache=True)
 VerdictService(sys.argv[1], cfg).start()
 print("ready", flush=True)
 time.sleep(600)

@@ -47,7 +47,6 @@ def _service(tmp_path, name, **cfg_kw):
     defaults = dict(
         batch_timeout_ms=2.0,
         batch_flows=256,
-        dispatch_mode="eager",
     )
     defaults.update(cfg_kw)
     cfg = DaemonConfig(**defaults)
@@ -424,7 +423,7 @@ def test_reconnect_renegotiates_fresh_rings(tmp_path):
 
         inst.reset_module_registry()
         svc2 = VerdictService(path, DaemonConfig(
-            batch_timeout_ms=2.0, batch_flows=256, dispatch_mode="eager",
+            batch_timeout_ms=2.0, batch_flows=256,
         )).start()
         try:
             _wait(

@@ -66,10 +66,10 @@ def service(tmp_path):
 
 @pytest.fixture
 def client(service):
-    # The service's first engine prewarm runs the dispatch-mode probe
-    # (eager AND jit compiles) lazily inside whichever RPC triggers it;
-    # compile wall-time late in a long pytest process can exceed the
-    # default 10s RPC timeout and flake the test that got unlucky.
+    # The service's first engine prewarm compiles every bucket lazily
+    # inside whichever RPC triggers it; compile wall-time late in a
+    # long pytest process can exceed the default 10s RPC timeout and
+    # flake the test that got unlucky.
     c = SidecarClient(service.socket_path, timeout=60.0)
     yield c
     c.close()
@@ -356,23 +356,19 @@ def test_sidecar_fast_path_used(service, client):
     assert service.fast_log.requests >= 8
 
 
-# --- dispatch mode / verdict device (measured config) ---------------------
+# --- verdict device (measured config) -------------------------------------
 
-@pytest.mark.parametrize(
-    "mode,device",
-    [("eager", "default"), ("jit", "default"), ("eager", "cpu")],
-)
-def test_sidecar_dispatch_modes_bit_identical(tmp_path, mode, device):
-    """Eager and jitted dispatch (and the cpu-backed verdict device the
-    co-located latbench mode uses) render identical verdicts vs the
-    oracle — the dispatch choice is performance config, never policy."""
+@pytest.mark.parametrize("device", ["default", "cpu"])
+def test_sidecar_dispatch_modes_bit_identical(tmp_path, device):
+    """The default device and the cpu-backed verdict device the
+    co-located latbench mode uses render identical verdicts vs the
+    oracle — the verdict device is performance config, never policy."""
     inst.reset_module_registry()
     cfg = DaemonConfig(
-        batch_timeout_ms=2.0, batch_flows=512,
-        dispatch_mode=mode, verdict_device=device,
+        batch_timeout_ms=2.0, batch_flows=512, verdict_device=device,
     )
     svc = VerdictService(
-        str(tmp_path / f"verdict-{mode}-{device}.sock"), cfg
+        str(tmp_path / f"verdict-{device}.sock"), cfg
     ).start()
     try:
         c = SidecarClient(svc.socket_path, timeout=60.0)
@@ -380,30 +376,6 @@ def test_sidecar_dispatch_modes_bit_identical(tmp_path, mode, device):
             exp = oracle_ops(r2d2_policy(), CORPUS)
             got = shim_ops(c, CORPUS)
             assert_parity(got, exp)
-            assert svc.dispatch_mode_chosen == mode
-        finally:
-            c.close()
-    finally:
-        svc.stop()
-        inst.reset_module_registry()
-
-
-def test_sidecar_dispatch_auto_resolves_by_measurement(tmp_path):
-    """dispatch_mode='auto' must resolve to a concrete measured choice
-    at first engine prewarm."""
-    inst.reset_module_registry()
-    cfg = DaemonConfig(
-        batch_timeout_ms=2.0, batch_flows=512, dispatch_mode="auto"
-    )
-    svc = VerdictService(str(tmp_path / "verdict-auto.sock"), cfg).start()
-    try:
-        assert svc.dispatch_mode_chosen is None
-        c = SidecarClient(svc.socket_path, timeout=60.0)
-        try:
-            exp = oracle_ops(r2d2_policy(), CORPUS)
-            got = shim_ops(c, CORPUS)
-            assert_parity(got, exp)
-            assert svc.dispatch_mode_chosen in ("eager", "jit")
         finally:
             c.close()
     finally:

@@ -30,58 +30,54 @@ from cilium_tpu.utils.option import DaemonConfig
 from test_sidecar import CORPUS, assert_parity, oracle_ops, r2d2_policy
 
 
-# A pipelined (two-frame) entry routes through the entrywise engine
-# path, whose model calls dispatch eagerly on the dispatcher thread —
-# the spot where a host-visible stall/crash manifests.  (Single-frame
-# entries ride the vectorized path, whose gather+model executable was
-# jit-compiled at prewarm and never re-enters the Python wrapper.)
+# Every device launch of the service goes through its jit launcher
+# (``VerdictService._jit_for``): the round paths, the engines' pump, the
+# gather path and the quarantine re-probe alike.  A pipelined
+# (two-frame) entry rides the engine slow path, whose launch runs on the
+# dispatcher thread — the spot where a hung/crashed device manifests.
 PIPELINED = b"READ /public/a.txt\r\nHALT\r\n"
 
 
-class FaultModel:
-    """Wraps a real verdict model with injectable faults: ``stall``
-    blocks every call until cleared (a hung TPU / compile storm);
-    ``crash`` raises (a poisoned engine)."""
+class FaultLauncher:
+    """Injectable faults on the service's device launches: ``stall``
+    blocks every launch until cleared (a hung TPU / compile storm);
+    ``crash`` raises (a poisoned engine); ``calls`` counts launches."""
 
     MAX_STALL_S = 30.0  # leak guard: a stuck thread frees itself in CI
 
-    def __init__(self, inner):
-        self.inner = inner
+    def __init__(self):
         self.stall = threading.Event()
         self.crash = threading.Event()
         self.calls = 0
 
-    def __call__(self, data, lengths, remotes):
-        self.calls += 1
-        waited = 0.0
-        while self.stall.is_set() and waited < self.MAX_STALL_S:
-            time.sleep(0.01)
-            waited += 0.01
-        if self.crash.is_set():
-            raise RuntimeError("injected model crash")
-        return self.inner(data, lengths, remotes)
+    def wrap(self, fn):
+        def launch(*args):
+            self.calls += 1
+            waited = 0.0
+            while self.stall.is_set() and waited < self.MAX_STALL_S:
+                time.sleep(0.01)
+                waited += 0.01
+            if self.crash.is_set():
+                raise RuntimeError("injected device crash")
+            return fn(*args)
+
+        return launch
 
 
 @pytest.fixture
-def fault_model(monkeypatch):
-    """Every r2d2 model built by the service is wrapped in a FaultModel;
-    the fixture hands the test the live wrapper(s)."""
-    import cilium_tpu.models.r2d2 as r2d2mod
-
-    built: list[FaultModel] = []
-    orig = r2d2mod.build_r2d2_model
-
-    def wrapped(*a, **kw):
-        m = FaultModel(orig(*a, **kw))
-        built.append(m)
-        return m
-
-    monkeypatch.setattr(r2d2mod, "build_r2d2_model", wrapped)
-    yield built
+def fault_device(monkeypatch):
+    """Every executable the service's launcher hands out is gated by
+    one FaultLauncher; the fixture hands the test that gate."""
+    gate = FaultLauncher()
+    jit_for = VerdictService._jit_for
+    monkeypatch.setattr(
+        VerdictService, "_jit_for",
+        lambda self, *a, **kw: gate.wrap(jit_for(self, *a, **kw)),
+    )
+    yield gate
     # Never leave a thread parked on the gate (conftest leak guard).
-    for m in built:
-        m.stall.clear()
-        m.crash.clear()
+    gate.stall.clear()
+    gate.crash.clear()
 
 
 def _service(tmp_path, name, **cfg_kw):
@@ -89,7 +85,6 @@ def _service(tmp_path, name, **cfg_kw):
     defaults = dict(
         batch_timeout_ms=2.0,
         batch_flows=256,
-        dispatch_mode="eager",
     )
     defaults.update(cfg_kw)
     cfg = DaemonConfig(**defaults)
@@ -133,7 +128,7 @@ def _wait(predicate, timeout_s, what):
 
 # --- hung device: quarantine + bit-identical fallback + heal ---------------
 
-def test_hung_model_quarantine_fallback_and_heal(tmp_path, fault_model):
+def test_hung_model_quarantine_fallback_and_heal(tmp_path, fault_device):
     """The acceptance scenario: with the model stalled, the service
     keeps rendering verdicts through the host fallback (bit-identical
     to the oracle on the same inputs), the stuck round is shed TYPED
@@ -153,8 +148,7 @@ def test_hung_model_quarantine_fallback_and_heal(tmp_path, fault_model):
             "sidecar-pol",
         )
         assert res == int(FilterResult.OK)
-        assert fault_model, "service built no r2d2 model"
-        model = fault_model[0]
+        assert fault_device.calls, "service launched no model"
 
         # Baseline: device path, parity with the oracle.
         assert_parity(
@@ -163,7 +157,7 @@ def test_hung_model_quarantine_fallback_and_heal(tmp_path, fault_model):
 
         # Stall the device.  The in-flight round is deposed by the
         # watchdog and answered with a typed SHED — never a hang.
-        model.stall.set()
+        fault_device.stall.set()
         stalled_result = {}
 
         def stalled_request():
@@ -197,7 +191,7 @@ def test_hung_model_quarantine_fallback_and_heal(tmp_path, fault_model):
         assert st["containment"]["stalls"] >= 1
 
         # Stall clears -> traffic-driven re-probe heals automatically.
-        model.stall.clear()
+        fault_device.stall.clear()
         def poke_and_check():
             _shim_run(client, shim_b, [b"HALT\r\n"])
             return not svc.guard.quarantined
@@ -205,19 +199,18 @@ def test_hung_model_quarantine_fallback_and_heal(tmp_path, fault_model):
 
         # Healed: parity still holds and the device path resumes (the
         # demoted conn rebinds its engine; new traffic hits the model).
-        calls_before = model.calls
+        calls_before = fault_device.calls
         assert_parity(
             _shim_run(client, shim_b, CORPUS), oracle_ops(r2d2_policy(), CORPUS)
         )
-        _shim_run(client, shim_b, [PIPELINED])  # eager-path round
+        _shim_run(client, shim_b, [PIPELINED])  # engine slow-path round
         _wait(
-            lambda: model.calls > calls_before, 5.0,
+            lambda: fault_device.calls > calls_before, 5.0,
             "device path resumed after heal",
         )
         assert svc.status()["containment"]["quarantined"] is False
     finally:
-        for m in fault_model:
-            m.stall.clear()
+        fault_device.stall.clear()
         client.close()
         svc.stop()
         inst.reset_module_registry()
@@ -225,7 +218,7 @@ def test_hung_model_quarantine_fallback_and_heal(tmp_path, fault_model):
 
 # --- crashed batch: typed per-entry errors, poisoned-engine quarantine -----
 
-def test_batch_crash_typed_errors_then_quarantine(tmp_path, fault_model):
+def test_batch_crash_typed_errors_then_quarantine(tmp_path, fault_device):
     svc = _service(
         tmp_path, "crash",
         device_call_timeout_s=5.0,
@@ -235,13 +228,12 @@ def test_batch_crash_typed_errors_then_quarantine(tmp_path, fault_model):
     client = SidecarClient(svc.socket_path, timeout=10.0)
     try:
         _, shim = _open_conn(client, 7101)
-        model = fault_model[0]
         assert_parity(
             _shim_run(client, shim, CORPUS[:2]),
             oracle_ops(r2d2_policy(), CORPUS[:2]),
         )
 
-        model.crash.set()
+        fault_device.crash.set()
         # Every crashed round answers EVERY entry with a typed error —
         # promptly, with no client hang.
         for _ in range(3):
@@ -261,7 +253,7 @@ def test_batch_crash_typed_errors_then_quarantine(tmp_path, fault_model):
         assert_parity(got, oracle_ops(r2d2_policy(), CORPUS))
 
         # Fix the model -> automatic re-probe heals.
-        model.crash.clear()
+        fault_device.crash.clear()
         def poke():
             _shim_run(client, shim, [b"HALT\r\n"])
             return not svc.guard.quarantined
@@ -271,8 +263,7 @@ def test_batch_crash_typed_errors_then_quarantine(tmp_path, fault_model):
             oracle_ops(r2d2_policy(), CORPUS[:3]),
         )
     finally:
-        for m in fault_model:
-            m.crash.clear()
+        fault_device.crash.clear()
         client.close()
         svc.stop()
         inst.reset_module_registry()
@@ -280,7 +271,7 @@ def test_batch_crash_typed_errors_then_quarantine(tmp_path, fault_model):
 
 # --- overload: bounded queue, typed sheds, zero silent loss ----------------
 
-def test_overload_shed_bounded_zero_silent_loss(tmp_path, fault_model):
+def test_overload_shed_bounded_zero_silent_loss(tmp_path, fault_device):
     svc = _service(
         tmp_path, "overload",
         device_call_timeout_s=10.0,  # no deposal: pure queue pressure
@@ -290,7 +281,6 @@ def test_overload_shed_bounded_zero_silent_loss(tmp_path, fault_model):
     client = SidecarClient(svc.socket_path, timeout=20.0)
     try:
         _, shim = _open_conn(client, 7201)
-        model = fault_model[0]
         _shim_run(client, shim, [b"HALT\r\n"])  # engine warm
 
         answered: dict[int, int] = {}
@@ -306,7 +296,7 @@ def test_overload_shed_bounded_zero_silent_loss(tmp_path, fault_model):
         # Stall the worker (a pipelined round pins it inside the model
         # call) so the queue builds past the 8-entry cap, then release.
         # Every entry must be answered: OK or typed SHED.
-        model.stall.set()
+        fault_device.stall.set()
         occupier = threading.Thread(
             target=lambda: client._on_data_rpc(
                 shim.conn_id, False, False, PIPELINED
@@ -320,7 +310,7 @@ def test_overload_shed_bounded_zero_silent_loss(tmp_path, fault_model):
                 1000 + k, [shim.conn_id], [0], [len(msg)], msg
             )
         time.sleep(0.3)
-        model.stall.clear()
+        fault_device.stall.clear()
         occupier.join(10.0)
         assert not occupier.is_alive()
         assert done.wait(15.0), (
@@ -333,15 +323,14 @@ def test_overload_shed_bounded_zero_silent_loss(tmp_path, fault_model):
         assert st["containment"]["shed_entries"] > 0, "queue cap never shed"
         assert st["dispatcher"]["shed_submits"] > 0
     finally:
-        for m in fault_model:
-            m.stall.clear()
+        fault_device.stall.clear()
         client.verdict_callback = None
         client.close()
         svc.stop()
         inst.reset_module_registry()
 
 
-def test_wire_deadline_sheds_typed(tmp_path, fault_model):
+def test_wire_deadline_sheds_typed(tmp_path, fault_device):
     """A per-entry deadline propagated from on_io over the wire: queue
     time past the budget sheds with a typed SHED verdict."""
     svc = _service(
@@ -357,10 +346,9 @@ def test_wire_deadline_sheds_typed(tmp_path, fault_model):
             "sidecar-pol",
         )
         assert res == int(FilterResult.OK)
-        model = fault_model[0]
         _shim_run(client, shim_a, [b"HALT\r\n"])  # engine warm
 
-        model.stall.set()
+        fault_device.stall.set()
         results = {}
 
         def slow_req():  # occupies the worker for the stall duration
@@ -383,15 +371,14 @@ def test_wire_deadline_sheds_typed(tmp_path, fault_model):
         tb = threading.Thread(target=dl_req)
         tb.start()
         time.sleep(0.4)
-        model.stall.clear()
+        fault_device.stall.clear()
         ta.join(10.0)
         tb.join(10.0)
         assert not ta.is_alive() and not tb.is_alive()
         assert results["a"] == int(FilterResult.OK)  # stall < watchdog
         assert results["b"] == int(FilterResult.SHED)
     finally:
-        for m in fault_model:
-            m.stall.clear()
+        fault_device.stall.clear()
         client.close()
         svc.stop()
         inst.reset_module_registry()
@@ -443,7 +430,7 @@ def test_client_reconnect_after_service_restart(tmp_path):
         # client reconnects and REPLAYS modules, policies, conns.
         inst.reset_module_registry()
         svc2 = VerdictService(path, DaemonConfig(
-            batch_timeout_ms=2.0, batch_flows=256, dispatch_mode="eager",
+            batch_timeout_ms=2.0, batch_flows=256,
         )).start()
         try:
             _wait(
@@ -516,7 +503,7 @@ def test_reconnect_single_loop_when_replay_socket_dies(tmp_path):
         # verdicts flow, and the loop winds down.
         inst.reset_module_registry()
         svc2 = VerdictService(path, DaemonConfig(
-            batch_timeout_ms=2.0, batch_flows=256, dispatch_mode="eager",
+            batch_timeout_ms=2.0, batch_flows=256,
         )).start()
         try:
             _wait(
@@ -663,7 +650,7 @@ def test_cut_through_survives_mid_round_deposal(tmp_path):
     shim connection) and leaks the old lock held forever."""
     svc = VerdictService(
         str(tmp_path / "ct.sock"),
-        DaemonConfig(batch_timeout_ms=0.0, dispatch_mode="eager"),
+        DaemonConfig(batch_timeout_ms=0.0),
     )
     disp = svc.dispatcher
     old_lock = disp._in_process_lock
@@ -692,7 +679,7 @@ def test_send_loop_suppresses_only_shed_rounds(tmp_path):
     still in the pipeline are emitted — never silently lost."""
     svc = VerdictService(
         str(tmp_path / "sl.sock"),
-        DaemonConfig(batch_timeout_ms=2.0, dispatch_mode="eager"),
+        DaemonConfig(batch_timeout_ms=2.0),
     )
 
     import socket
@@ -747,7 +734,7 @@ def test_crash_containment_skips_answered_items(tmp_path):
     shim already consumed would desync it."""
     svc = VerdictService(
         str(tmp_path / "cc.sock"),
-        DaemonConfig(batch_timeout_ms=2.0, dispatch_mode="eager"),
+        DaemonConfig(batch_timeout_ms=2.0),
     )
 
     class _Probe:
@@ -816,7 +803,7 @@ def test_send_marks_answered_under_write_lock(tmp_path):
 
     svc = VerdictService(
         str(tmp_path / "wl.sock"),
-        DaemonConfig(batch_timeout_ms=2.0, dispatch_mode="eager"),
+        DaemonConfig(batch_timeout_ms=2.0),
     )
     a_sock, b_sock = socket.socketpair()
     try:
@@ -867,8 +854,7 @@ def test_send_marks_answered_under_write_lock(tmp_path):
             pass
 
 
-def test_cut_through_stall_on_idle_service_is_shed(tmp_path, fault_model,
-                                                   monkeypatch):
+def test_cut_through_stall_on_idle_service_is_shed(tmp_path, fault_device):
     """Greedy mode, idle service: the round runs inline on the shim
     reader thread (cut-through), where a hung device call used to be
     invisible to the stall watchdog (_busy never set — no deposal, no
@@ -883,23 +869,9 @@ def test_cut_through_stall_on_idle_service_is_shed(tmp_path, fault_model,
         device_reprobe_interval_s=30.0,  # no heal during the test
     )
     client = SidecarClient(svc.socket_path, timeout=10.0)
-    model = None
     try:
         _, shim = _open_conn(client, 7701)
-        model = fault_model[-1]
-        # Prewarm compiled the gather executable, which never re-enters
-        # the Python model: hang its dispatch as well.
-        gathered = svc._gathered_call
-
-        def stalled_gather(*a, **kw):
-            waited = 0.0
-            while model.stall.is_set() and waited < model.MAX_STALL_S:
-                time.sleep(0.01)
-                waited += 0.01
-            return gathered(*a, **kw)
-
-        monkeypatch.setattr(svc, "_gathered_call", stalled_gather)
-        model.stall.set()
+        fault_device.stall.set()
         t0 = time.monotonic()
         result, entries = client._on_data_rpc(
             shim.conn_id, False, False, b"HALT\r\n"
@@ -913,8 +885,7 @@ def test_cut_through_stall_on_idle_service_is_shed(tmp_path, fault_model,
         assert svc.guard.quarantined
         assert svc.dispatcher.stall_deposals >= 1
     finally:
-        if model is not None:
-            model.stall.clear()
+        fault_device.stall.clear()
         client.close()
         # Wait for the unstuck reader thread to drain out of the
         # service (it prunes itself from _clients on exit): a daemon
@@ -962,7 +933,7 @@ def test_zombie_round_guard_calls_are_suppressed(tmp_path):
     rounds — deposal already booked the stall."""
     svc = VerdictService(
         str(tmp_path / "zg.sock"),
-        DaemonConfig(batch_timeout_ms=2.0, dispatch_mode="eager"),
+        DaemonConfig(batch_timeout_ms=2.0),
     )
     cur = threading.current_thread()
     try:
@@ -1092,7 +1063,7 @@ def test_cut_through_releases_lock_before_round_close(tmp_path):
     completing just past the deadline is deposed and double-replied."""
     svc = VerdictService(
         str(tmp_path / "ord.sock"),
-        DaemonConfig(batch_timeout_ms=0.0, dispatch_mode="eager"),
+        DaemonConfig(batch_timeout_ms=0.0),
     )
     disp = svc.dispatcher
     svc._process = lambda items: None
@@ -1139,7 +1110,7 @@ def test_guard_deferred_failures_hold_streak_across_rounds():
 
 # --- latency decomposition across the degradation ladder -------------------
 
-def test_stage_histograms_follow_degradation_ladder(tmp_path, fault_model):
+def test_stage_histograms_follow_degradation_ladder(tmp_path, fault_device):
     """PR 4 acceptance: stage histograms and trace exemplars carry the
     correct serving-path label at every rung of the PR 2 ladder —
     vec (device vectorized) → oracle (entrywise slow path) →
@@ -1166,7 +1137,6 @@ def test_stage_histograms_follow_degradation_ladder(tmp_path, fault_model):
 
     try:
         _, shim = _open_conn(client, 9301)
-        model = fault_model[0]
         base = e2e_counts()
         base_q = stage_counts("queue")
 
@@ -1186,7 +1156,7 @@ def test_stage_histograms_follow_degradation_ladder(tmp_path, fault_model):
 
         # Rung 3 — shed: a deadline-stamped entry queued behind a
         # stalled round sheds typed, labeled shed.
-        model.stall.set()
+        fault_device.stall.set()
         results = {}
 
         def slow_req():
@@ -1215,7 +1185,7 @@ def test_stage_histograms_follow_degradation_ladder(tmp_path, fault_model):
         tb.start()
         time.sleep(0.4)
 
-        model.stall.clear()
+        fault_device.stall.clear()
         t.join(10.0)
         tb.join(10.0)
         assert not t.is_alive() and not tb.is_alive()
@@ -1227,13 +1197,13 @@ def test_stage_histograms_follow_degradation_ladder(tmp_path, fault_model):
         # model re-wedged so traffic-driven probes hang and the
         # quarantine HOLDS; the fallback serves bit-identically and
         # its rounds are labeled host.
-        model.stall.set()
+        fault_device.stall.set()
         svc.guard.record_stall("ladder-stall")
         assert svc.guard.quarantined
         _shim_run(client, shim, [b"READ /public/fallback.txt\r\n"])
         _wait(lambda: e2e_counts()["host"] > base["host"], 10,
               "host e2e histogram")
-        model.stall.clear()
+        fault_device.stall.clear()
 
         # Every rung also observed its queue stage...
         after_q = stage_counts("queue")
@@ -1256,8 +1226,7 @@ def test_stage_histograms_follow_degradation_ladder(tmp_path, fault_model):
         assert set(lat["stages"]) >= set(paths)
         assert lat["slow_exemplars"] > 0
     finally:
-        for fm in fault_model:
-            fm.stall.clear()
+        fault_device.stall.clear()
         client.close()
         svc.stop()
         inst.reset_module_registry()

@@ -66,7 +66,7 @@ class _Route:
     (the round items' ``client``)."""
 
     def __init__(self, tmp, name: str, base: int, **cfg_kw):
-        cfg = DaemonConfig(batch_flows=256, dispatch_mode="jit", **cfg_kw)
+        cfg = DaemonConfig(batch_flows=256, **cfg_kw)
         self.svc = VerdictService(str(tmp / f"{name}.sock"), cfg).start()
         if name == "per_item":
             self.svc._run_mat_group = lambda items, t_pop: False
